@@ -38,6 +38,9 @@ def main() -> None:
     args = ap.parse_args()
 
     import importlib
+
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     all_fails = []
     for name, mod_name in BENCHES:
         if args.only and args.only not in name:

@@ -10,10 +10,12 @@ import tempfile
 sys.path.insert(0, "src")
 
 
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.train import train
 
 
 def main():
+    configure_compile_cache()
     with tempfile.TemporaryDirectory() as d:
         out = train("olmo-1b", steps=12, reduced=True, global_batch=2,
                     seq_len=64, ckpt_dir=d, ckpt_every=4, log_every=4,

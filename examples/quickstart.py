@@ -27,9 +27,11 @@ from repro.core.metadata import synth_filesystem
 from repro.core.monitor import Monitor, MonitorConfig
 from repro.core.query import QueryEngine
 from repro.core.sketches.ddsketch import DDSketchConfig
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main():
+    configure_compile_cache()
     print("== 1. snapshot ==")
     table = synth_filesystem(20_000, n_users=32, n_groups=8, seed=42)
     print(f"synthetic FS: {len(table)} objects")

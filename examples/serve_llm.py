@@ -20,11 +20,13 @@ import numpy as np
 from repro import models
 from repro.configs import get_config
 from repro.data.specs import reduced_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving.engine import greedy_sample, make_serve_step
 from repro.serving.quant import dequantize_params, quantize_params
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--batch", type=int, default=4)

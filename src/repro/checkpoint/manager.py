@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-from repro.compat import zstd
+import zstandard as zstd
 
 
 def _flatten(tree) -> Dict[str, Any]:

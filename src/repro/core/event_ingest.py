@@ -21,8 +21,9 @@ Pipeline per applied batch, mirroring the paper's Flink ingest job:
    per applied batch (batched slot assignment; columnar scatters).
 4. **Aggregate index**: grouped per-principal updates on device — object
    counts through the ``segstats`` kernel, attribute sketches through the
-   grouped-DDSketch kernel (``use_kernel=True``) or their jnp references
-   — then republish only the touched principals.
+   grouped-DDSketch kernel (compiled on a TPU; their jnp references on
+   the CPU, ``repro.kernels.on_tpu``) — then republish only the touched
+   principals.
 
 Consistency modes (paper's tunable consistency/latency/freshness knobs):
 
@@ -83,6 +84,8 @@ from repro.core.index import (AggregateIndex, PrimaryIndex, bucket_pow2,
                               pack_array, pad_1d, unpack_array)
 from repro.core.sketches import ddsketch as dds
 from repro.core.telemetry import resolve as _resolve_tel
+from repro.kernels.ddsketch import ops as dd_ops
+from repro.kernels.segstats import ops as seg_ops
 
 MODES = ("eager", "buffered")
 
@@ -95,7 +98,6 @@ class IngestConfig:
     freshness_window: float = 5.0    # buffered: max seconds before an apply
     max_buffer_events: int = 8192    # buffered: size trigger
     pad_to: int = 1024               # pad device batches (stable jit shapes)
-    use_kernel: bool = False         # Pallas segstats/ddsketch kernels
     filter_opens: bool = True        # drop OPEN events before coalescing
     update_aggregates: bool = True   # maintain the aggregate index too
     track_hierarchy: bool = True     # maintain subtree rollups (§14)
@@ -135,33 +137,17 @@ class Watermark:
 # device steps (jitted once per (config, padded-shape))
 # ---------------------------------------------------------------------------
 
-def _fold_sketch(scfg, state, vals, pids, mask, update_grouped):
+@functools.partial(jax.jit, static_argnums=(0,))
+def _sketch_apply(scfg: dds.DDSketchConfig, state, vals, pids, mask):
     """state (P, A, NB); vals (A, N); pids/mask (N,): per-attribute
-    grouped update, generic over the update implementation."""
+    grouped update through the DDSketch kernel's entry point."""
     n_principals = state["count"].shape[0]
     for ai in range(vals.shape[0]):
         sub = jax.tree.map(lambda s: s[:, ai], state)
-        sub = update_grouped(scfg, sub, vals[ai], pids, n_principals,
-                             mask=mask)
+        sub = dd_ops.update_grouped(scfg, sub, vals[ai], pids, n_principals,
+                                    mask=mask)
         state = jax.tree.map(lambda s, ns: s.at[:, ai].set(ns), state, sub)
     return state
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _sketch_apply_ref(scfg: dds.DDSketchConfig, state, vals, pids, mask):
-    return _fold_sketch(scfg, state, vals, pids, mask, dds.update_grouped)
-
-
-def _sketch_apply_kernel(scfg, state, vals, pids, mask):
-    from repro.kernels.ddsketch import ops as dd_ops
-    return _fold_sketch(scfg, state, vals, pids, mask,
-                        dd_ops.update_grouped)
-
-
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _count_apply_ref(pids, sids, weights, n_principals, n_shards):
-    counts = jnp.zeros((n_principals, n_shards), jnp.float32)
-    return counts.at[pids, sids].add(weights)
 
 
 # shared with AggregateIndex publication: one bucketing rule, one shape
@@ -1221,9 +1207,7 @@ class EventIngestor:
             npad = _bucket(len(pid_cat), self.cfg.pad_to)
             vals_p = np.stack([_pad(vals_cat[a], npad)
                                for a in range(vals_cat.shape[0])])
-            apply_fn = (_sketch_apply_kernel if self.cfg.use_kernel
-                        else _sketch_apply_ref)
-            self._sketch_state = apply_fn(
+            self._sketch_state = _sketch_apply(
                 cfg.sketch, self._sketch_state, jnp.asarray(vals_p),
                 jnp.asarray(_pad(pid_cat, npad).astype(np.int32)),
                 jnp.asarray(_pad(w_cat, npad)))
@@ -1241,13 +1225,7 @@ class EventIngestor:
                 counts=self._exact_counts())
 
     def _count_step(self, pids, sids, weights):
-        if self.cfg.use_kernel:
-            from repro.kernels.segstats import ops as seg_ops
-            seg = seg_ops.segstats(pids, sids, weights, weights,
-                                   self.pcfg.n_principals,
-                                   self.pcfg.n_shards)
-            return seg["counts"]
-        return _count_apply_ref(pids.astype(jnp.int32),
-                                sids.astype(jnp.int32),
-                                weights.astype(jnp.float32),
-                                self.pcfg.n_principals, self.pcfg.n_shards)
+        seg = seg_ops.segstats(pids.astype(jnp.int32), sids.astype(jnp.int32),
+                               weights, weights, self.pcfg.n_principals,
+                               self.pcfg.n_shards)
+        return seg["counts"]

@@ -36,8 +36,8 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
+import zstandard as zstd
 
-from repro.compat import zstd
 from repro.core import metadata as md
 from repro.core.sketches import ddsketch as dds
 from repro.core.telemetry import get_telemetry

@@ -202,7 +202,7 @@ class QueryService:
     def __init__(self, primary, aggregate: Optional[AggregateIndex] = None,
                  ingestor=None, now=None, max_readers: int = 16,
                  cache_capacity: int = 256, pin_aggregate: bool = True,
-                 now_bucket_s: float = 1.0, use_kernels=None,
+                 now_bucket_s: float = 1.0, use_kernels: bool = True,
                  telemetry=None):
         """``now_bucket_s``: freshness bucket for TIME-RELATIVE query
         caching (``not_accessed_since`` / ``large_cold_files`` /
@@ -213,7 +213,7 @@ class QueryService:
         bucket, and answers can never be more than one bucket stale in
         wall-clock terms. <= 0 keys on the raw clock (every call
         misses). ``use_kernels`` passes through to the snapshot
-        engines (core/query.py; None = auto)."""
+        engines (core/query.py)."""
         self.primary = primary
         self.aggregate = aggregate if aggregate is not None \
             else AggregateIndex()

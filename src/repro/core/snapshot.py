@@ -23,11 +23,12 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import metadata as md
 from repro.core.sketches import ddsketch as dds
+from repro.kernels.ddsketch import ops as dd_ops
 
 ATTRS = ("size", "atime", "ctime", "mtime")
 
@@ -227,9 +228,10 @@ def aggregate_local(cfg: PipelineConfig, rows: Dict, valid) -> Dict:
 
 
 def make_aggregate_step(cfg: PipelineConfig, mesh, dp_axes=("data",),
-                        tp_axis="model", use_kernel: bool = False,
-                        scatter_merge: bool = False):
-    """scatter_merge: reduce-scatter the sketch merge over the DP axes
+                        tp_axis="model", scatter_merge: bool = False):
+    """Grouped DDSketch per principal x attribute through the DDSketch
+    kernel's entry point (compiled on a TPU, its jnp reference on the
+    CPU). scatter_merge: reduce-scatter the sketch merge over the DP axes
     (halves merge wire bytes; output principals shard over tp x dp)."""
     n_tp = mesh.shape[tp_axis]
     assert cfg.n_principals % n_tp == 0
@@ -250,15 +252,9 @@ def make_aggregate_step(cfg: PipelineConfig, mesh, dp_axes=("data",),
             for pid, m in _principal_streams(cfg, rows):
                 lp = pid - p0
                 sel = ((lp >= 0) & (lp < p_loc)).astype(jnp.float32)
-                if use_kernel:
-                    from repro.kernels.ddsketch import ops as dd_ops
-                    sub = dd_ops.update_grouped(
-                        cfg.sketch, sub, vals, jnp.clip(lp, 0, p_loc - 1),
-                        p_loc, mask=m * sel * vmask)
-                else:
-                    sub = dds.update_grouped(
-                        cfg.sketch, sub, vals, jnp.clip(lp, 0, p_loc - 1),
-                        p_loc, mask=m * sel * vmask)
+                sub = dd_ops.update_grouped(
+                    cfg.sketch, sub, vals, jnp.clip(lp, 0, p_loc - 1),
+                    p_loc, mask=m * sel * vmask)
             state = jax.tree.map(lambda s, ns: s.at[:, ai].set(ns), state, sub)
         if scatter_merge:
             return dds.merge_psum_scatter(state, dp_axes)

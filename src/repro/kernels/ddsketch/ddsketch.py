@@ -7,38 +7,45 @@ accumulation is a ONE-HOT MXU CONTRACTION —
     counts[p, b] += sum_r onehot_P[r, p] * onehot_B[r, b]
                  == (onehot_P^T @ onehot_B)[p, b]
 
-i.e. an (P_BLK x ROWS) @ (ROWS x NB) matmul per tile, which the MXU eats at
-full rate (all dims padded to multiples of 128). The remaining per-
-principal moments (count/total/min/max/zero) are VPU row reductions over
-the same one-hot.
+i.e. a (P_BLK x ROWS) @ (NB x ROWS)^T matmul per tile, contracted over
+the row (lane) axis, which the MXU eats at full rate (all dims padded to
+multiples of 128). The remaining per-principal moments
+(count/total/min/max/zero) are lane reductions over the same one-hot.
+
+Bucket ids come from ``sketches.ddsketch.bucket_index`` (XLA, fused
+ahead of the kernel), so the kernel bins exactly as the reference does.
+Rows stream in as (1, ROWS) lane-major blocks and both one-hots are
+built as (classes, ROWS) from a sublane iota, so no row vector is ever
+reshaped into a column (the TPU compiler refuses that cast for masks).
+Moments come out as (P_BLK, 1) columns.
 
 Grid: (P_blocks, N_blocks); output blocks are indexed by the principal
 block only, so they stay VMEM-resident across the inner (row) grid
 dimension and accumulate in place.
 
 VMEM budget per step (defaults ROWS=512, P_BLK=128, NB=2048, f32):
-  onehot_P 512x128 (256 KB) + onehot_B 512x2048 (4 MB)
+  onehot_P 128x512 (256 KB) + onehot_B 2048x512 (4 MB)
   + counts 128x2048 (1 MB) + row vectors  ==>  ~5.5 MB  (< 16 MB VMEM).
 """
 from __future__ import annotations
 
 import functools
-import math
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.sketches import ddsketch as dds
 from repro.core.sketches.ddsketch import DDSketchConfig
 
 NEG_BIG = -3.0e38
 POS_BIG = 3.0e38
 
 
-def _kernel(vals_ref, pids_ref, mask_ref,
+def _kernel(idx_ref, vals_ref, pids_ref, mask_ref,
             counts_ref, zero_ref, cnt_ref, tot_ref, min_ref, max_ref,
-            *, cfg: DDSketchConfig, p_block: int):
+            *, p_block: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
@@ -48,46 +55,41 @@ def _kernel(vals_ref, pids_ref, mask_ref,
         min_ref[...] = jnp.full_like(min_ref, POS_BIG)
         max_ref[...] = jnp.full_like(max_ref, NEG_BIG)
 
-    v = vals_ref[...].astype(jnp.float32)          # (ROWS,)
-    pid = pids_ref[...]                            # (ROWS,) int32 (global)
-    m = mask_ref[...].astype(jnp.float32)          # (ROWS,)
+    idx = idx_ref[...]                             # (1, ROWS) int32, -1 = zero
+    v = vals_ref[...]                              # (1, ROWS) float32
+    pid = pids_ref[...]                            # (1, ROWS) int32 (global)
+    m = mask_ref[...]                              # (1, ROWS) float32
     nb = counts_ref.shape[1]
 
-    # log-bucketize (VPU)
-    safe = jnp.maximum(v, cfg.min_value)
-    idx = jnp.ceil(jnp.log(safe) * (1.0 / math.log(cfg.gamma))
-                   ).astype(jnp.int32) + cfg.offset
-    idx = jnp.clip(idx, 0, nb - 1)
-    is_zero = v <= cfg.min_value
+    # principal one-hot restricted to this block, weighted by the mask
+    p_iota = (jax.lax.broadcasted_iota(jnp.int32, (p_block, 1), 0)
+              + pl.program_id(0) * p_block)
+    in_p = pid == p_iota                           # (P_BLK, ROWS)
+    onehot_p = jnp.where(in_p, m, 0.0)
 
-    # principal one-hot restricted to this block
-    p0 = pl.program_id(0) * p_block
-    lp = pid - p0
-    sel = (lp >= 0) & (lp < p_block)
-    lpc = jnp.clip(lp, 0, p_block - 1)
-    onehot_p = ((lpc[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, p_block), 1)) & sel[:, None]).astype(jnp.float32)
-    onehot_p = onehot_p * m[:, None]               # weighted by mask
-
-    # bucket one-hot (zero-bucket rows excluded)
-    onehot_b = ((idx[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, nb), 1)) & (~is_zero)[:, None]).astype(jnp.float32)
+    # bucket one-hot (zero-bucket rows carry -1 and match no bucket)
+    onehot_b = jnp.where(
+        idx == jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0),
+        1.0, 0.0)                                  # (NB, ROWS)
 
     # MXU: histogram block accumulate
     counts_ref[...] += jax.lax.dot_general(
-        onehot_p, onehot_b, (((0,), (0,)), ((), ())),
+        onehot_p, onehot_b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
     # VPU: per-principal moments
-    zero_ref[...] += jnp.sum(onehot_p * is_zero[:, None].astype(jnp.float32),
-                             axis=0)
-    cnt_ref[...] += jnp.sum(onehot_p, axis=0)
-    tot_ref[...] += jnp.sum(onehot_p * v[:, None], axis=0)
-    live = (onehot_p > 0)
+    zero_ref[...] += jnp.sum(jnp.where(idx < 0, onehot_p, 0.0), axis=1,
+                             keepdims=True)
+    cnt_ref[...] += jnp.sum(onehot_p, axis=1, keepdims=True)
+    tot_ref[...] += jnp.sum(onehot_p * v, axis=1, keepdims=True)
+    live = in_p & (m > 0)
     min_ref[...] = jnp.minimum(
-        min_ref[...], jnp.min(jnp.where(live, v[:, None], POS_BIG), axis=0))
+        min_ref[...],
+        jnp.min(jnp.where(live, v, POS_BIG), axis=1, keepdims=True))
     max_ref[...] = jnp.maximum(
-        max_ref[...], jnp.max(jnp.where(live, v[:, None], NEG_BIG), axis=0))
+        max_ref[...],
+        jnp.max(jnp.where(live, v, NEG_BIG), axis=1, keepdims=True))
 
 
 def grouped_update_pallas(cfg: DDSketchConfig, values: jax.Array,
@@ -100,48 +102,34 @@ def grouped_update_pallas(cfg: DDSketchConfig, values: jax.Array,
     n = values.shape[0]
     n_pad = -(-n // rows) * rows
     p_pad = -(-n_principals // p_block) * p_block
-    if n_pad != n:
-        pad = n_pad - n
-        values = jnp.pad(values, (0, pad))
-        pids = jnp.pad(pids, (0, pad))
-        mask = jnp.pad(mask, (0, pad))
     nb = cfg.n_buckets
 
+    def row(x, dtype):
+        return jnp.pad(x.astype(dtype), (0, n_pad - n)).reshape(1, n_pad)
+
     grid = (p_pad // p_block, n_pad // rows)
-    out_shapes = (
-        jax.ShapeDtypeStruct((p_pad, nb), jnp.float32),   # counts
-        jax.ShapeDtypeStruct((p_pad,), jnp.float32),      # zero
-        jax.ShapeDtypeStruct((p_pad,), jnp.float32),      # count
-        jax.ShapeDtypeStruct((p_pad,), jnp.float32),      # total
-        jax.ShapeDtypeStruct((p_pad,), jnp.float32),      # min
-        jax.ShapeDtypeStruct((p_pad,), jnp.float32),      # max
-    )
-    in_specs = [
-        pl.BlockSpec((rows,), lambda i, j: (j,)),
-        pl.BlockSpec((rows,), lambda i, j: (j,)),
-        pl.BlockSpec((rows,), lambda i, j: (j,)),
-    ]
-    vec_spec = pl.BlockSpec((p_block,), lambda i, j: (i,))
-    out_specs = (
-        pl.BlockSpec((p_block, nb), lambda i, j: (i, 0)),
-        vec_spec, vec_spec, vec_spec, vec_spec, vec_spec,
-    )
+    col = jax.ShapeDtypeStruct((p_pad, 1), jnp.float32)
+    col_spec = pl.BlockSpec((p_block, 1), lambda i, j: (i, 0))
     counts, zero, cnt, tot, mn, mx = pl.pallas_call(
-        functools.partial(_kernel, cfg=cfg, p_block=p_block),
+        functools.partial(_kernel, p_block=p_block),
         grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
+        in_specs=[pl.BlockSpec((1, rows), lambda i, j: (0, j))] * 4,
+        out_specs=(pl.BlockSpec((p_block, nb), lambda i, j: (i, 0)),)
+        + (col_spec,) * 5,
+        out_shape=(jax.ShapeDtypeStruct((p_pad, nb), jnp.float32),)
+        + (col,) * 5,                  # zero, count, total, min, max
         interpret=interpret,
-    )(values.astype(jnp.float32), pids.astype(jnp.int32),
-      mask.astype(jnp.float32))
+    )(row(dds.bucket_index(cfg, values), jnp.int32),
+      row(values, jnp.float32), row(pids, jnp.int32),
+      row(mask, jnp.float32))
 
     sl = slice(0, n_principals)
+    mn, mx = mn[sl, 0], mx[sl, 0]
     return {
         "counts": counts[sl],
-        "zero_count": zero[sl],
-        "count": cnt[sl],
-        "total": tot[sl],
-        "min": jnp.where(mn[sl] >= POS_BIG, jnp.inf, mn[sl]),
-        "max": jnp.where(mx[sl] <= NEG_BIG, -jnp.inf, mx[sl]),
+        "zero_count": zero[sl, 0],
+        "count": cnt[sl, 0],
+        "total": tot[sl, 0],
+        "min": jnp.where(mn >= POS_BIG, jnp.inf, mn),
+        "max": jnp.where(mx <= NEG_BIG, -jnp.inf, mx),
     }

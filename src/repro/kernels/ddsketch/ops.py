@@ -1,5 +1,10 @@
-"""jit'd public wrapper for the grouped-DDSketch Pallas kernel, signature-
-compatible with sketches.ddsketch.update_grouped."""
+"""Public entry point for the grouped DDSketch update, signature-
+compatible with sketches.ddsketch.update_grouped. The platform picks the
+form (``repro.kernels.on_tpu``): the compiled Pallas kernel on a TPU,
+the production jnp update (the kernel's reference) on the CPU, where
+per-grid-step Pallas interpretation would dominate the ingest and
+snapshot hot paths. ``kernel_update_grouped`` is the kernel path itself;
+tests run it in interpret mode."""
 from __future__ import annotations
 
 import functools
@@ -10,17 +15,19 @@ import jax.numpy as jnp
 
 from repro.core.sketches import ddsketch as dds
 from repro.core.sketches.ddsketch import DDSketchConfig
+from repro.kernels import on_tpu
 from repro.kernels.ddsketch.ddsketch import grouped_update_pallas
 
-# interpret=True on CPU (this container); on TPU set REPRO_PALLAS_COMPILE=1.
-import os
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
-
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def _delta(cfg: DDSketchConfig, values, pids, mask, n_principals):
-    return grouped_update_pallas(cfg, values, pids, mask, n_principals,
-                                 interpret=INTERPRET)
+@functools.partial(jax.jit, static_argnums=(0, 4),
+                   static_argnames=("interpret",))
+def kernel_update_grouped(cfg: DDSketchConfig, state: Dict,
+                          values: jax.Array, pids: jax.Array,
+                          n_principals: int, mask: jax.Array, *,
+                          interpret: bool = False) -> Dict:
+    delta = grouped_update_pallas(cfg, values, pids, mask, n_principals,
+                                  interpret=interpret)
+    return dds.merge(state, delta)
 
 
 def update_grouped(cfg: DDSketchConfig, state: Dict, values: jax.Array,
@@ -28,5 +35,8 @@ def update_grouped(cfg: DDSketchConfig, state: Dict, values: jax.Array,
                    mask: Optional[jax.Array] = None) -> Dict:
     if mask is None:
         mask = jnp.ones_like(values, jnp.float32)
-    delta = _delta(cfg, values, pids, mask, n_principals)
-    return dds.merge(state, delta)
+    if on_tpu():
+        return kernel_update_grouped(cfg, state, values, pids,
+                                     n_principals, mask)
+    return dds.update_grouped(cfg, state, values, pids, n_principals,
+                              mask=mask)

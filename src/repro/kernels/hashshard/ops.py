@@ -1,33 +1,31 @@
-"""jit'd wrapper for the hashshard kernel."""
+"""jit'd wrappers for the hashshard kernel."""
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
+from repro.kernels import on_tpu
 from repro.kernels.hashshard.hashshard import hashshard_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+from repro.kernels.hashshard.ref import hashshard_ref
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
-def hashshard(byte_rows: jax.Array, lengths: jax.Array, n_shards: int = 64):
-    return hashshard_pallas(byte_rows, lengths, n_shards,
-                            interpret=INTERPRET)
+def _hashshard_compiled(byte_rows: jax.Array, lengths: jax.Array,
+                        n_shards: int):
+    return hashshard_pallas(byte_rows, lengths, n_shards, interpret=False)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _hashshard_oracle(byte_rows: jax.Array, lengths: jax.Array,
-                      n_shards: int = 64):
-    from repro.kernels.hashshard.ref import hashshard_ref
+                      n_shards: int):
     return hashshard_ref(byte_rows, lengths, n_shards)
 
 
 def hashshard_route(byte_rows, lengths, n_shards: int = 64):
-    """Batch-routing entry point for the sharded index: the Pallas
-    kernel when compiled (TPU), its jitted jnp oracle under interpret
-    mode — per-grid-step interpretation would dominate a CPU routing hot
-    path. Identical outputs either way (test_kernels pins them)."""
-    fn = _hashshard_oracle if INTERPRET else hashshard
+    """Batch-routing entry point for the sharded index: the compiled
+    Pallas kernel on a TPU, its jitted jnp oracle on the CPU, where
+    per-grid-step interpretation would dominate a routing hot path.
+    Identical outputs either way (test_kernels pins them)."""
+    fn = _hashshard_compiled if on_tpu() else _hashshard_oracle
     return fn(byte_rows, lengths, n_shards)

@@ -1,17 +1,12 @@
 """Dispatch layer for the fused predicate kernel (DESIGN.md §13).
 
 Same contract as the sibling kernel packages (ddsketch / segstats /
-hashshard): callers get one entry point per op and never see jax —
-``AVAILABLE`` is False when jax cannot import, and every op then runs
-the pure-numpy oracle in ref.py (the host fallback the planner also
-uses for inexpressible programs).
-
-Default mode is ``INTERPRET`` (the repo-wide convention): the jitted
-whole-array jax.numpy oracle IS the production CPU route, because
-per-grid-step Pallas interpretation dominates on CPU. Setting
-``REPRO_PALLAS_COMPILE=1`` compiles the real Pallas kernel for TPU
-runs. All three implementations (Pallas / jnp / numpy) are bit-for-bit
-identical on the packed bitmaps — tests/test_predeval.py pins it.
+hashshard): callers get one entry point per op, and the platform picks
+its form (``repro.kernels.on_tpu``). On a TPU ``predeval_words`` runs
+the compiled Pallas kernel; on the CPU it runs the jitted whole-array
+jax.numpy oracle, because per-grid-step Pallas interpretation dominates
+there. Both are bit-for-bit identical to the numpy host oracle in
+ref.py on the packed bitmaps — tests/test_predeval.py pins it.
 
 ``Arena`` is the device-resident stacked column slab for one shard at
 one mutation epoch: (3, n_pad) float32 + (3, n_pad) int32 + alive,
@@ -24,23 +19,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
-from typing import Dict, Optional
+from typing import Dict
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import on_tpu
 from repro.kernels.predeval import ref
 from repro.kernels.predeval.ref import BLOCK_ROWS, FLOAT_COLS, PRED_COLUMNS
-
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
-try:
-    import jax
-    import jax.numpy as jnp
-    AVAILABLE = True
-except Exception:                              # pragma: no cover
-    jax = jnp = None
-    AVAILABLE = False
 
 
 def _pad_rows(n: int) -> int:
@@ -52,13 +39,13 @@ def _pad_rows(n: int) -> int:
 
 @dataclasses.dataclass
 class Arena:
-    """Stacked column slab for one shard epoch (device arrays when jax
-    is available, numpy otherwise). ``n`` is the true row count; rows
-    n..n_pad-1 are zero-padding with alive=0."""
+    """Stacked column slab for one shard epoch, as device arrays. ``n``
+    is the true row count; rows n..n_pad-1 are zero-padding with
+    alive=0."""
 
-    fcols: object          # (3, n_pad) float32
-    icols: object          # (3, n_pad) int32
-    alive: object          # (n_pad,) int32
+    fcols: jax.Array       # (3, n_pad) float32
+    icols: jax.Array       # (3, n_pad) int32
+    alive: jax.Array       # (n_pad,) int32
     n: int
     n_pad: int
 
@@ -86,10 +73,8 @@ def pack_arena(columns: Dict[str, np.ndarray], alive: np.ndarray,
             icols[i - FLOAT_COLS, :n] = arr[:n]
     av = np.zeros(n_pad, np.int32)
     av[:n] = alive[:n]
-    if AVAILABLE:
-        return Arena(jnp.asarray(fcols), jnp.asarray(icols),
-                     jnp.asarray(av), n, n_pad)
-    return Arena(fcols, icols, av, n, n_pad)
+    return Arena(jnp.asarray(fcols), jnp.asarray(icols), jnp.asarray(av),
+                 n, n_pad)
 
 
 @functools.lru_cache(maxsize=None)
@@ -113,10 +98,7 @@ def _jitted(has_set: bool, use_pallas: bool):
 def predeval_words(arena: Arena, progs: ref.Programs) -> np.ndarray:
     """(k_pad, n_pad/32) uint32 packed bitmaps for the program batch —
     one fused read of the arena regardless of K."""
-    if not AVAILABLE:
-        return ref.predeval_host(arena.fcols, arena.icols, arena.alive,
-                                 progs)
-    fn = _jitted(progs.has_set, not INTERPRET)
+    fn = _jitted(progs.has_set, on_tpu())
     out = fn(arena.fcols, arena.icols, arena.alive,
              jnp.asarray(progs.ops), jnp.asarray(progs.lo),
              jnp.asarray(progs.hi), jnp.asarray(progs.msk),

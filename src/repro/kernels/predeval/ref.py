@@ -30,15 +30,16 @@ Bitmap format: row r of program k is bit (r % 32) of word
 ``words[k, r // 32]`` — uint32 words, little-endian bit order, i.e.
 exactly ``np.packbits(match, bitorder="little").view(np.uint32)``.
 
-Everything here is pure numpy (no jax import at module scope) so the
-compiler, the zone batch op, and the host oracle also serve as the
-jax-absent fallback path.
+The compiler, the zone batch op and the host oracle are pure numpy;
+``predeval_ref`` is the jax.numpy oracle.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 #: kernel column order; first FLOAT_COLS are float32 arenas, rest int32
@@ -236,7 +237,7 @@ def stack_programs(programs: Sequence[dict]) -> Programs:
 
 
 # ---------------------------------------------------------------------------
-# host (numpy) oracle — also the jax-absent fallback evaluator
+# host (numpy) oracle
 # ---------------------------------------------------------------------------
 
 def pack_words(match: np.ndarray) -> np.ndarray:
@@ -286,18 +287,13 @@ def predeval_host(fcols: np.ndarray, icols: np.ndarray, alive: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# jnp oracle — the compiled CPU route (jitted by ops.py) and the
-# interpret-mode stand-in for the Pallas kernel
+# jnp oracle — the CPU route (jitted by ops.py)
 # ---------------------------------------------------------------------------
 
 def predeval_ref(fcols, icols, alive, ops, lo, hi, msk,
                  setrows, setcol, setvals, has_set: bool):
     """Whole-array jax.numpy evaluator with the exact kernel semantics;
-    traced under jit by ops.py (jax imported lazily so this module
-    stays importable without jax)."""
-    import jax
-    import jax.numpy as jnp
-
+    traced under jit by ops.py."""
     k_pad = ops.shape[0]
     n = fcols.shape[1]
     match = jnp.broadcast_to((alive != 0)[None, :], (k_pad, n))

@@ -98,8 +98,7 @@ def lower_cell(arch: str, shape_name: str, mesh, step_override=None):
 def analyze_compiled(lowered, compiled, cfg, shape, mesh) -> Dict:
     from repro.analysis.hlocost import analyze_hlo
 
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     try:
         ma = compiled.memory_analysis()
         mem = {

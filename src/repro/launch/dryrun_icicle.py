@@ -26,7 +26,6 @@ from typing import Dict  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from repro.compat import shard_map  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import events as ev  # noqa: E402

@@ -11,13 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older versions default to
-    # Auto axes and reject the kwarg.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

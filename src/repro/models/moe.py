@@ -18,8 +18,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import PD, activation_fn

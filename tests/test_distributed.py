@@ -140,6 +140,9 @@ SCRIPT = textwrap.dedent("""
 def test_distributed_suite():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # host devices only: a child must never reach for a chip this
+    # process (or another test worker) may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", SCRIPT], cwd=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), env=env,
         capture_output=True, text=True, timeout=1500)

@@ -5,6 +5,8 @@ the primary index equal to a snapshot of the final state — including
 renames, deletes, and replaying the same events twice (idempotency by the
 shared snapshot/changelog version clock).
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,7 @@ from repro.core.metadata import crc32_shard, path_hash
 from repro.core.monitor import Monitor, MonitorConfig
 from repro.core.query import QueryEngine
 from repro.core.sketches.ddsketch import DDSketchConfig
+from repro.kernels.segstats import ops as seg_ops
 
 PCFG = snap.PipelineConfig(
     n_users=8, n_groups=4, n_dirs=20,
@@ -195,12 +198,16 @@ def reference_counts(prim):
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
-def test_aggregate_counts_match_segstats_reference(use_kernel):
+def test_aggregate_counts_match_segstats_reference(use_kernel, monkeypatch):
     """After an event batch (incl. deletes + a rename), the maintained
     (P, S) counts equal a from-scratch reference over the live index —
-    with both the jnp path and the Pallas segstats kernel."""
+    with both the CPU form of the segstats entry point (its jnp oracle)
+    and the Pallas segstats kernel (interpret mode) swapped in."""
+    if use_kernel:
+        monkeypatch.setattr(seg_ops, "segstats", functools.partial(
+            seg_ops.segstats_kernel, interpret=True))
     s, *_ = scripted_stream()
-    ing, prim, agg = make_ingestor(use_kernel=use_kernel)
+    ing, prim, agg = make_ingestor()
     drain(s, ing)
     np.testing.assert_allclose(ing.counts, reference_counts(prim))
 

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.hlocost import analyze_hlo, parse_computations
-from repro.compat import cost_analysis
 
 
 def _compile(fn, *args):
@@ -38,7 +37,7 @@ def test_matches_xla_on_scan_free():
     b = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     comp = _compile(fn, a, b)
     mine = analyze_hlo(comp.as_text()).flops
-    xla = cost_analysis(comp)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert abs(mine - xla) / xla < 0.15, (mine, xla)
 
 

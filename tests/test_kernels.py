@@ -72,7 +72,7 @@ def test_hashshard_kernel_matches_host():
 def test_hashshard_property(strings):
     rows, lens = encode_strings(strings, width=64)
     h_dev, s_dev = hashshard_pallas(jnp.asarray(rows), jnp.asarray(lens),
-                                    rows=64)
+                                    rows=128)
     # device hash of the truncated utf-8 == host hash of the same bytes
     for i, s in enumerate(strings):
         raw = s.encode("utf-8")[:64]
@@ -103,14 +103,47 @@ def test_segstats_kernel_matches_ref(n, p, s):
 
 
 def test_kernel_ops_wrappers():
-    """ops.py wrappers: jit + state merge path."""
+    """ops.py entry points: the kernel path (jit + state merge, interpret
+    mode here) and the CPU form both match the jnp references, for the
+    DDSketch update onto a non-empty state and for segstats."""
     from repro.core.sketches import ddsketch as dds
     from repro.kernels.ddsketch import ops as dd_ops
+    from repro.kernels.segstats import ops as seg_ops
     cfg = DDSketchConfig(n_buckets=512)
     rng = np.random.default_rng(3)
     vals = jnp.asarray(rng.lognormal(8, 2, 500), jnp.float32)
     pids = jnp.asarray(rng.integers(0, 10, 500), jnp.int32)
-    state = dds.init(cfg, (10,))
-    got = dd_ops.update_grouped(cfg, state, vals, pids, 10)
+    mask = jnp.ones_like(vals)
+    state = dds.update_grouped(cfg, dds.init(cfg, (10,)), vals[::-1], pids,
+                               10)
     want = dds.update_grouped(cfg, state, vals, pids, 10)
-    _cmp_state(got, want, 10)
+    _cmp_state(dd_ops.kernel_update_grouped(cfg, state, vals, pids, 10, mask,
+                                            interpret=True), want, 10)
+    _cmp_state(dd_ops.update_grouped(cfg, state, vals, pids, 10), want, 10)
+
+    sids = jnp.asarray(rng.integers(0, 64, 500), jnp.int32)
+    seg_want = segstats_ref(pids, sids, vals, mask, 10, 64)
+    for got in (seg_ops.segstats_kernel(pids, sids, vals, mask, 10, 64,
+                                        interpret=True),
+                seg_ops.segstats(pids, sids, vals, mask, 10, 64)):
+        for k in ("counts", "min", "max"):
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(seg_want[k]))
+        np.testing.assert_allclose(np.asarray(got["sum"]),
+                                   np.asarray(seg_want["sum"]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("backend,compiled", [("tpu", True), ("cpu", False),
+                                              ("gpu", None)])
+def test_backend_choice(monkeypatch, backend, compiled):
+    """The platform alone picks the kernel form: compiled Pallas on a
+    TPU, the jnp oracle on the CPU, an error elsewhere."""
+    import jax
+
+    from repro.kernels import on_tpu
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if compiled is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            on_tpu()
+    else:
+        assert on_tpu() is compiled

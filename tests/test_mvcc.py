@@ -15,9 +15,6 @@ The invariants:
   watermark, exactly;
 - closing every snapshot returns pin refcounts to baseline and disarms
   copy-on-write.
-
-Runs under the deterministic hypothesis stub (tests/conftest.py) or the
-real library when installed.
 """
 import itertools
 

@@ -171,7 +171,7 @@ def test_float32_size_threshold_boundaries_agree_across_routes():
                            use_kernels=use_kernels)
 
     scan = build(False, False)
-    kern = build(None, False)
+    kern = build(True, False)
     disc = build(False, True)
     f32 = np.array(sizes, np.float32)
     for t in thresholds:

@@ -215,7 +215,7 @@ def test_checkpoint_write_is_atomic(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# property-based offset semantics (hypothesis; stub-compatible)
+# property-based offset semantics (hypothesis)
 # ---------------------------------------------------------------------------
 
 def _create_batch(fids):

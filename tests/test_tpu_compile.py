@@ -1,0 +1,141 @@
+"""Compile the four Pallas kernels for a described TPU v5e chip at the
+widths the main path runs them, and check the compile-cache helper.
+
+Interpret mode accepts block shapes, casts and layouts the TPU compiler
+refuses; these compiles catch that without a chip. The topology is
+described inside a fixture (never at import time), so every test worker
+collects the same tests and only the worker running this file loads the
+TPU compiler library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.sketches.ddsketch import DDSketchConfig
+from repro.core.snapshot import PipelineConfig
+from repro.kernels.ddsketch.ddsketch import grouped_update_pallas
+from repro.kernels.hashshard.hashshard import hashshard_pallas
+from repro.kernels.predeval.predeval import predeval
+from repro.kernels.predeval.ref import PRED_COLUMNS, SET_CAP
+from repro.kernels.segstats.segstats import segstats_pallas
+from repro.launch import compile_cache
+
+ARENA_ROWS = 1 << 20          # one shard's arena at 4M records / 4 shards
+BATCH_ROWS = 1 << 16          # a routed / ingested device batch
+ROUTE_WIDTH = 192             # ShardedPrimaryIndex.route_width
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    old_log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was_on = jax.config.jax_enable_compilation_cache
+    # compiles for a described chip are written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+        if old_log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("has_set", [False, True])
+def test_predeval_compiles_for_v5e(one_chip, has_set):
+    k, ks = 32, 4
+    cols = len(PRED_COLUMNS)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(*a):
+        return predeval(*a, has_set=has_set)
+    _compile(fn, s((3, ARENA_ROWS), jnp.float32),
+             s((3, ARENA_ROWS), jnp.int32), s((ARENA_ROWS,), jnp.int32),
+             s((k, cols), jnp.int32), s((k, cols), jnp.float32),
+             s((k, cols), jnp.float32), s((k, cols), jnp.int32),
+             s((ks,), jnp.int32), s((ks,), jnp.int32),
+             s((ks, SET_CAP), jnp.int32))
+
+
+def test_segstats_compiles_for_v5e(one_chip):
+    cfg = PipelineConfig()
+    i32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.int32, sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.float32, sharding=one_chip)
+
+    def fn(p, s, v, m):
+        return segstats_pallas(p, s, v, m, cfg.n_principals, cfg.n_shards,
+                               rows=512, p_block=128, interpret=False)
+    _compile(fn, i32, i32, f32, f32)
+
+
+def test_ddsketch_compiles_for_v5e(one_chip):
+    pcfg = PipelineConfig()
+    cfg = DDSketchConfig(n_buckets=2048)
+    i32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.int32, sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.float32, sharding=one_chip)
+
+    def fn(v, p, m):
+        return grouped_update_pallas(cfg, v, p, m, pcfg.n_principals,
+                                     rows=512, p_block=128, interpret=False)
+    _compile(fn, f32, i32, f32)
+
+
+def test_hashshard_compiles_for_v5e(one_chip):
+    rows = jax.ShapeDtypeStruct((BATCH_ROWS, ROUTE_WIDTH), jnp.uint8,
+                                sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.int32, sharding=one_chip)
+
+    def fn(b, n):
+        return hashshard_pallas(b, n, 4, interpret=False)
+    _compile(fn, rows, lens)
+
+
+@pytest.fixture
+def cache_dir_config():
+    old = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compile_cache_keeps_env_dir(monkeypatch, cache_dir_config, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_inside_checkout(monkeypatch,
+                                                cache_dir_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = compile_cache.configure_compile_cache()
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert got == os.path.join(checkout, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == got
+    # the same path on every call: a cache that moves never hits
+    assert compile_cache.configure_compile_cache() == got
+    with open(os.path.join(checkout, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
