@@ -25,7 +25,6 @@ BENCHES = [
     ("query_service", "benchmarks.bench_query_service"),
     ("replication", "benchmarks.bench_replication"),
     ("rollup", "benchmarks.bench_rollup"),
-    ("telemetry", "benchmarks.bench_telemetry"),
     ("fig3_5_scaling", "benchmarks.bench_scaling"),
     ("table1_queries", "benchmarks.bench_index_query"),
     ("roofline", "benchmarks.bench_roofline"),

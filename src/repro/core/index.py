@@ -40,7 +40,7 @@ import zstandard as zstd
 
 from repro.core import metadata as md
 from repro.core.sketches import ddsketch as dds
-from repro.core.telemetry import get_telemetry
+from repro.core.telemetry import resolve as _resolve_tel
 
 
 def atomic_write_blob(path: str, obj, pre_replace: Optional[Callable] = None
@@ -250,6 +250,16 @@ class PrimaryIndex:
     #: open-snapshot refcounts keyed by the mutation epoch they pinned
     _snap_refs: Dict[int, int] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    #: telemetry handle (None: the process default, resolved at
+    #: construction); it only observes and is NOT serialized
+    telemetry: Optional[object] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    def __post_init__(self):
+        self.telemetry = _resolve_tel(self.telemetry)
+        # upsert_batch's stage spans, bound once
+        self._span_assign = self.telemetry.span("index.upsert.assign")
+        self._span_write = self.telemetry.span("index.upsert.write")
 
     def _mutated(self, slots: Optional[np.ndarray] = None) -> None:
         """Epoch bump + delta publication to the attached discovery
@@ -463,10 +473,8 @@ class PrimaryIndex:
             cap *= 2
         if cap == cur:
             return
-        # PrimaryIndex is a serialized dataclass, so it carries no
-        # telemetry field — growth/compaction are cold paths and read
-        # the process default lazily
-        tel = get_telemetry()
+        # growth/compaction are cold paths: families looked up per call
+        tel = self.telemetry
         tel.counter("index_arena_growth_total",
                     "arena doubling events").inc()
         tel.counter("index_arena_grown_rows_total",
@@ -565,33 +573,36 @@ class PrimaryIndex:
                                            np.asarray(v).dtype)
         if hashes is None and "path_hash" in fields:
             hashes = np.asarray(fields["path_hash"], np.uint32)
-        slots, new_mask = self.slot_map.assign(paths, hashes)
-        self._ensure_capacity(max(0, len(self.slot_map) - len(self.paths)))
-        self._unshare("paths", "version", "alive", *fields)
-        if new_mask.any():
-            self.paths[slots[new_mask]] = np.asarray(
-                paths, object)[new_mask]
-            if self.tombstone_floor:
-                # fresh slots may be reclaimed tombstones: start them at
-                # the compaction floor so the >= gate below decides
-                self.version[slots[new_mask]] = self.tombstone_floor
-        prev_alive = self.alive[slots] & ~new_mask   # pre-batch liveness
-        ok = versions >= self.version[slots]
-        sel = slots[ok]
-        for k, v in fields.items():
-            self.columns[k][sel] = np.asarray(v)[ok]
-        self.version[sel] = versions[ok]
-        self.alive[sel] = True
-        entered = ok & ~prev_alive
-        # one +1 per slot even if the subject repeats within the batch
-        idx = np.nonzero(entered)[0]
-        out = np.zeros(n, bool)
-        if len(idx):
-            _, first_pos = np.unique(slots[idx], return_index=True)
-            out[idx[first_pos]] = True
-        # discovery delta: every touched slot (gated rows included —
-        # over-noting only costs a re-verify, never a wrong answer)
-        self._mutated(slots)
+        with self._span_assign:
+            slots, new_mask = self.slot_map.assign(paths, hashes)
+        with self._span_write:
+            self._ensure_capacity(max(0, len(self.slot_map)
+                                      - len(self.paths)))
+            self._unshare("paths", "version", "alive", *fields)
+            if new_mask.any():
+                self.paths[slots[new_mask]] = np.asarray(
+                    paths, object)[new_mask]
+                if self.tombstone_floor:
+                    # fresh slots may be reclaimed tombstones: start them
+                    # at the compaction floor so the >= gate below decides
+                    self.version[slots[new_mask]] = self.tombstone_floor
+            prev_alive = self.alive[slots] & ~new_mask   # pre-batch liveness
+            ok = versions >= self.version[slots]
+            sel = slots[ok]
+            for k, v in fields.items():
+                self.columns[k][sel] = np.asarray(v)[ok]
+            self.version[sel] = versions[ok]
+            self.alive[sel] = True
+            entered = ok & ~prev_alive
+            # one +1 per slot even if the subject repeats within the batch
+            idx = np.nonzero(entered)[0]
+            out = np.zeros(n, bool)
+            if len(idx):
+                _, first_pos = np.unique(slots[idx], return_index=True)
+                out[idx[first_pos]] = True
+            # discovery delta: every touched slot (gated rows included —
+            # over-noting only costs a re-verify, never a wrong answer)
+            self._mutated(slots)
         return out
 
     @_locked
@@ -676,7 +687,7 @@ class PrimaryIndex:
         dead = n - len(live_slots)
         if dead == 0:
             return 0
-        tel = get_telemetry()
+        tel = self.telemetry
         t0 = tel.clock()
         dead_vers = self.version[:n][~self.alive[:n]]
         self.tombstone_floor = max(self.tombstone_floor,

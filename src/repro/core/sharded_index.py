@@ -259,14 +259,20 @@ class ShardedPrimaryIndex:
         self.kernel_route_min = kernel_route_min
         self.route_width = route_width
         self.slot_map_factory = slot_map_factory
+        self.telemetry = _resolve_tel(telemetry)
         self.shards: List[PrimaryIndex] = [
-            PrimaryIndex(slot_map=slot_map_factory())
+            PrimaryIndex(slot_map=slot_map_factory(),
+                         telemetry=self.telemetry)
             for _ in range(n_shards)]
         self.rollups = None
+        # stage spans of route and upsert_batch, bound once; each run
+        # per batch, on the host, outside every jitted function
+        self._span_encode = self.telemetry.span("index.route.encode")
+        self._span_device = self.telemetry.span("index.route.device")
+        self._span_split = self.telemetry.span("index.upsert.split")
         # per-shard routed-record counters, bound once: the mutation
         # loops run per shard already, so the only extra cost per apply
         # is one inc per non-empty shard slice
-        self.telemetry = _resolve_tel(telemetry)
         fam = self.telemetry.counter(
             "shard_mutation_records_total",
             "records routed to each shard by mutation kind",
@@ -311,13 +317,16 @@ class ShardedPrimaryIndex:
         from repro.kernels.hashshard import ops as hs_ops
         from repro.kernels.hashshard.ref import encode_strings_np
         n = len(paths)
-        rows, lens, truncated = encode_strings_np(paths, self.route_width)
-        pad = bucket_pow2(n) - n          # O(log N) jit shape universe
-        if pad:
-            rows = np.pad(rows, ((0, pad), (0, 0)))
-            lens = np.pad(lens, (0, pad))
-        h, _ = hs_ops.hashshard_route(rows, lens, self.n_shards)
-        h = np.asarray(h[:n], np.uint32).copy()
+        with self._span_encode:
+            rows, lens, truncated = encode_strings_np(paths,
+                                                      self.route_width)
+        with self._span_device:           # H2D, kernel, readback
+            pad = bucket_pow2(n) - n      # O(log N) jit shape universe
+            if pad:
+                rows = np.pad(rows, ((0, pad), (0, 0)))
+                lens = np.pad(lens, (0, pad))
+            h, _ = hs_ops.hashshard_route(rows, lens, self.n_shards)
+            h = np.asarray(h[:n], np.uint32).copy()
         for i in np.nonzero(truncated)[0]:
             h[i] = md.path_hash(paths[i])
         return h
@@ -431,15 +440,18 @@ class ShardedPrimaryIndex:
             return np.zeros(0, bool)
         if hashes is None and "path_hash" in fields:
             hashes = np.asarray(fields["path_hash"], np.uint32)
-        h, sids = self.route(paths, hashes)
-        paths_arr = (paths if isinstance(paths, np.ndarray)
-                     else np.asarray(paths, object))
-        versions = np.broadcast_to(np.asarray(versions, np.int64), (n,))
-        order, bounds = self._order_split(sids)
-        paths_o = paths_arr[order]
-        vers_o = versions[order]
-        h_o = h[order]
-        fields_o = {k: np.asarray(v)[order] for k, v in fields.items()}
+        if hashes is None:                # hashing has its own spans
+            hashes, _ = self.route(paths)
+        with self._span_split:
+            h, sids = self.route(paths, hashes)
+            paths_arr = (paths if isinstance(paths, np.ndarray)
+                         else np.asarray(paths, object))
+            versions = np.broadcast_to(np.asarray(versions, np.int64), (n,))
+            order, bounds = self._order_split(sids)
+            paths_o = paths_arr[order]
+            vers_o = versions[order]
+            h_o = h[order]
+            fields_o = {k: np.asarray(v)[order] for k, v in fields.items()}
         out = np.zeros(n, bool)
         for s in range(self.n_shards):
             lo, hi = int(bounds[s]), int(bounds[s + 1])

@@ -23,6 +23,11 @@ without re-running a benchmark. Three pieces, one handle:
   the paper's freshness knob), and a *query* through the serving
   tier's route cascade (cache / discovery / kernel / scan) with
   per-stage timings and candidate counts from ``last_plan``.
+- **named spans**: ``span(name)`` binds a host span once; each entry
+  is a profiler annotation ``icicle.<name>`` (the device trace's
+  clock) and adds its seconds to ``span_seconds_total{span=<name>}``.
+  Components open them at stage boundaries, per batch, never per
+  record and never inside a jitted function.
 - **exposition**: ``snapshot()`` (JSON-able programmatic scrape),
   ``render_prometheus()`` (text format: ``# HELP``/``# TYPE``,
   cumulative ``_bucket{le=...}``/``_sum``/``_count``), a bounded JSONL
@@ -219,6 +224,44 @@ class Family:
         return out
 
 
+class Span:
+    """A named host span, made by ``Telemetry.span`` and held by its user.
+
+    ``with span:`` opens a ``jax.profiler.TraceAnnotation`` named
+    ``icicle.<name>`` (on the profiler's clock, so a trace shows it on
+    the host lines beside the device ops, nested under whatever was
+    open) and adds its duration on the telemetry clock to
+    ``span_seconds_total{span=<name>}``. No per-call record is kept: a
+    profiler trace holds those. Re-entrant and thread-safe: each thread
+    keeps its own stack of open entries."""
+
+    __slots__ = ("label", "_counter", "_clock", "_local", "_annotation")
+
+    def __init__(self, name: str, counter, clock: Callable[[], float]):
+        from jax.profiler import TraceAnnotation
+        self.label = "icicle." + name
+        self._counter = counter
+        self._clock = clock
+        self._local = threading.local()
+        self._annotation = TraceAnnotation
+
+    def __enter__(self):
+        ann = self._annotation(self.label)
+        ann.__enter__()
+        try:
+            stack = self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+        stack.append((ann, self._clock()))
+        return self
+
+    def __exit__(self, *exc):
+        ann, t0 = self._local.stack.pop()
+        self._counter.inc(self._clock() - t0)
+        ann.__exit__(*exc)
+        return False
+
+
 class QueryTrace:
     """One sampled query span. ``stage(label)`` stamps a relative
     offset; ``finish(...)`` seals the trace into the telemetry's ring
@@ -328,6 +371,15 @@ class Telemetry:
                   labels: Sequence[str] = ()) -> Family:
         return self._family("histogram", name, help, labels,
                             buckets=buckets)
+
+    def span(self, name: str) -> Span:
+        """A ``Span`` named ``name``. Bind it once and hold it: ``with
+        held_span:`` then costs no lookup and no formatting per call.
+        Spans bound under one name add to one series."""
+        fam = self.counter("span_seconds_total",
+                           "host seconds inside each named span",
+                           labels=("span",))
+        return Span(name, fam.labels(name), self.clock)
 
     def register_collector(self, fn: Callable[[], None]) -> None:
         """Run ``fn`` before every snapshot/render — the pull-time
@@ -536,6 +588,12 @@ class _NullInstrument:
     def quantile(self, q):
         return 0.0
 
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
 
 _NULL = _NullInstrument()
 
@@ -546,9 +604,8 @@ NULL_INSTRUMENT = _NULL
 
 class NullTelemetry:
     """Zero-cost opt-out: same surface as ``Telemetry``, every
-    instrument a shared no-op, every trace hook a pass. The overhead
-    bench (benchmarks/bench_telemetry.py) gates the instrumented hot
-    paths against this baseline."""
+    instrument (spans included) a shared no-op, every trace hook a
+    pass. Its spans open no profiler annotation."""
 
     enabled = False
     clock = staticmethod(time.perf_counter)
@@ -564,6 +621,9 @@ class NullTelemetry:
         return _NULL
 
     def histogram(self, name, help="", buckets=None, labels=()):
+        return _NULL
+
+    def span(self, name):
         return _NULL
 
     def register_collector(self, fn):

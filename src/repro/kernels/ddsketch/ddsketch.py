@@ -119,6 +119,7 @@ def grouped_update_pallas(cfg: DDSketchConfig, values: jax.Array,
         out_shape=(jax.ShapeDtypeStruct((p_pad, nb), jnp.float32),)
         + (col,) * 5,                  # zero, count, total, min, max
         interpret=interpret,
+        name="ddsketch_grouped_update",
     )(row(dds.bucket_index(cfg, values), jnp.int32),
       row(values, jnp.float32), row(pids, jnp.int32),
       row(mask, jnp.float32))

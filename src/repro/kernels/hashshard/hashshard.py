@@ -53,6 +53,7 @@ def hashshard_pallas(byte_rows: jax.Array, lengths: jax.Array,
         out_specs=pl.BlockSpec((sub, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad // LANES, LANES), jnp.int32),
         interpret=interpret,
+        name="hashshard",
     )(cols.reshape(w, n_pad // LANES, LANES),
       lens.reshape(n_pad // LANES, LANES))
     h = jax.lax.bitcast_convert_type(h.reshape(n_pad)[:n], jnp.uint32)

@@ -132,6 +132,7 @@ def predeval(fcols, icols, alive, ops, lo, hi, msk, setrows, setcol,
         out_specs=pl.BlockSpec((k8, WORDS), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((k8, n_pad // 32), jnp.int32),
         interpret=interpret,
+        name="predeval",
     )(ops, lo, hi, msk, setrows.reshape(ks, 1), setcol.reshape(ks, 1),
       setvals, jnp.asarray(_pack_matrix(), jnp.bfloat16), fcols, icols,
       alive.reshape(1, n_pad))

@@ -85,6 +85,7 @@ def segstats_pallas(pids: jax.Array, sids: jax.Array, values: jax.Array,
                    jax.ShapeDtypeStruct((p_pad, 1), jnp.float32),
                    jax.ShapeDtypeStruct((p_pad, 1), jnp.float32)),
         interpret=interpret,
+        name="segstats",
     )(row(pids, jnp.int32), row(sids, jnp.int32),
       row(values, jnp.float32), row(mask, jnp.float32))
     sl = slice(0, n_principals)

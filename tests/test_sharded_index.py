@@ -69,6 +69,41 @@ def test_device_route_matches_host_route():
     assert [int(h) for h in h_dev] == [path_hash(p) for p in paths]
 
 
+STAGE_SPANS = {"index.route.encode", "index.route.device",
+               "index.upsert.split", "index.upsert.assign",
+               "index.upsert.write"}
+
+
+@pytest.mark.parametrize("route_first", [True, False])
+def test_route_and_upsert_record_their_stage_spans(route_first):
+    """A device-routed batch records every stage span of route and
+    upsert, each a leaf: their sum never exceeds the elapsed time, also
+    when ``upsert_batch`` hashes the paths itself."""
+    import time
+
+    from repro.core.telemetry import Telemetry
+    tel = Telemetry()
+    idx = ShardedPrimaryIndex(4, kernel_route_min=8, route_width=64,
+                              telemetry=tel)
+    assert all(sh.telemetry is tel for sh in idx.shards)
+    paths = [f"/fs/d{i % 7}/f{i}" for i in range(300)]
+    sizes = np.arange(300, dtype=np.float32)
+    t0 = time.perf_counter()
+    if route_first:
+        h, _ = idx.route(paths)
+        idx.upsert_batch(paths, {"size": sizes, "path_hash": h},
+                         np.ones(300, np.int64), hashes=h)
+    else:
+        idx.upsert_batch(paths, {"size": sizes}, np.ones(300, np.int64))
+    elapsed = time.perf_counter() - t0
+    fam = tel.snapshot(traces=False)["metrics"]["span_seconds_total"]
+    got = {s["labels"]["span"]: s["value"] for s in fam["series"]}
+    assert set(got) == STAGE_SPANS
+    assert all(v > 0 for v in got.values())
+    assert sum(got.values()) <= elapsed
+    assert len(idx) == 300
+
+
 def test_pallas_kernel_route_parity():
     """The actual Pallas kernel (interpret mode) agrees with the jnp
     oracle the CPU routing path uses."""

@@ -10,9 +10,14 @@
   QUERY trace recording route + per-stage latency (both under
   injected deterministic clocks);
 - determinism: index state is byte-identical whether the pipeline
-  runs under a full Telemetry or a NullTelemetry.
+  runs under a full Telemetry or a NullTelemetry;
+- named spans: summed seconds per span on the telemetry clock, nesting
+  and re-entry, the NullTelemetry no-op, and the spans' place on the
+  profiler's host timeline.
 """
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -363,3 +368,100 @@ def _canon(obj):
     if isinstance(obj, bytes):
         return obj.hex()
     return obj
+
+
+# ---------------------------------------------------------------------------
+# named spans
+# ---------------------------------------------------------------------------
+
+def _span_seconds(tel, name):
+    return tel.counter("span_seconds_total").labels(name).value
+
+
+@pytest.mark.parametrize("calls", [1, 3])
+def test_span_sums_seconds_on_the_telemetry_clock(calls):
+    tel = _tel()                        # every clock read advances 1 ms
+    sp, again = tel.span("stage"), tel.span("stage")
+    for _ in range(calls):
+        with sp:
+            pass
+    with again:                         # one name, one series
+        pass
+    assert _span_seconds(tel, "stage") == pytest.approx((calls + 1) * 1e-3)
+    series = tel.snapshot(traces=False)["metrics"]["span_seconds_total"]
+    assert series["series"] == [{"labels": {"span": "stage"},
+                                 "value": pytest.approx((calls + 1) * 1e-3)}]
+    assert 'span_seconds_total{span="stage"}' in tel.render_prometheus()
+
+
+def test_spans_nest_and_reenter():
+    tel = _tel()
+    outer, inner = tel.span("outer"), tel.span("inner")
+    # clock reads: outer 1, inner 2, inner 3 | 4, 5, outer 6 (ms)
+    with outer:
+        with inner:
+            with inner:                 # a re-entry keeps its own start
+                pass
+    assert _span_seconds(tel, "inner") == pytest.approx(1e-3 + 3e-3)
+    assert _span_seconds(tel, "outer") == pytest.approx(5e-3)
+
+
+def test_span_exits_on_exception():
+    tel = _tel()
+    sp = tel.span("fails")
+    with pytest.raises(RuntimeError):
+        with sp:
+            raise RuntimeError("boom")
+    assert _span_seconds(tel, "fails") == pytest.approx(1e-3)
+    with sp:                            # the stack was unwound
+        pass
+    assert _span_seconds(tel, "fails") == pytest.approx(2e-3)
+
+
+def test_null_telemetry_span_is_a_shared_noop(monkeypatch):
+    import jax
+
+    def no_annotation(*a, **kw):
+        raise AssertionError("NullTelemetry opened a profiler annotation")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", no_annotation)
+    null = NullTelemetry()
+    sp = null.span("index.route.encode")
+    assert sp is NULL_INSTRUMENT and null.span("other") is sp
+    with sp:
+        with sp:
+            pass
+    assert null.snapshot(traces=False) == {"metrics": {}}
+
+
+def test_spans_sit_on_the_profiler_host_timeline(tmp_path):
+    """A CPU profiler trace holds the ``icicle.*`` annotations nested
+    inside an outer annotation on one host line: the spans share the
+    clock every host and device event of a trace is on."""
+    import jax
+    from jax.profiler import ProfileData
+    tel = Telemetry()
+    a, b = tel.span("a"), tel.span("b")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("outer"):
+            with a:
+                with b:
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    want = {"outer", "icicle.a", "icicle.b"}
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in want:
+                    found[e.name] = (plane.name, line.name,
+                                     e.start_ns, e.end_ns)
+    assert set(found) == want
+    assert {v[:2] for v in found.values()} == {found["outer"][:2]}
+    assert found["outer"][0] == "/host:CPU"
+    o, sa, sb = (found[k][2:] for k in ("outer", "icicle.a", "icicle.b"))
+    assert o[0] <= sa[0] <= sb[0] <= sb[1] <= sa[1] <= o[1]
+    assert _span_seconds(tel, "a") >= _span_seconds(tel, "b") > 0

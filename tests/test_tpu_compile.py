@@ -6,8 +6,14 @@ refuses; these compiles catch that without a chip. The topology is
 described inside a fixture (never at import time), so every test worker
 collects the same tests and only the worker running this file loads the
 TPU compiler library.
+
+Each kernel's custom call carries its ``name=``, and the benchmark's
+trace reduction (``bench/trace_reduce.kernel_of``, which goes by op
+shape) still classifies that line as the same kernel.
 """
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +28,10 @@ from repro.kernels.predeval.predeval import predeval
 from repro.kernels.predeval.ref import PRED_COLUMNS, SET_CAP
 from repro.kernels.segstats.segstats import segstats_pallas
 from repro.launch import compile_cache
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench"))
+import trace_reduce  # noqa: E402
 
 ARENA_ROWS = 1 << 20          # one shard's arena at 4M records / 4 shards
 BATCH_ROWS = 1 << 16          # a routed / ingested device batch
@@ -56,9 +66,23 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *shapes):
+def _compile(fn, *shapes, kernel):
+    """Compile ``fn`` for the chip; its one Pallas custom call is named
+    ``kernel`` and classified as ``kernel`` (the ``name=`` given) by
+    the trace reduction, from the HLO text with operand shapes the
+    profiler's op names carry."""
+    from jax._src.lib import xla_client
     compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    assert len(calls) == 1, calls
+    name, kind = kernel
+    assert re.match(rf"\s*(ROOT\s+)?%?{name}(\.\d+)?\s*=", calls[0]), \
+        calls[0][:120]
+    assert trace_reduce.kernel_of(calls[0]) == kind
     return compiled
 
 
@@ -77,7 +101,7 @@ def test_predeval_compiles_for_v5e(one_chip, has_set):
              s((k, cols), jnp.int32), s((k, cols), jnp.float32),
              s((k, cols), jnp.float32), s((k, cols), jnp.int32),
              s((ks,), jnp.int32), s((ks,), jnp.int32),
-             s((ks, SET_CAP), jnp.int32))
+             s((ks, SET_CAP), jnp.int32), kernel=("predeval", "predeval"))
 
 
 def test_segstats_compiles_for_v5e(one_chip):
@@ -88,7 +112,7 @@ def test_segstats_compiles_for_v5e(one_chip):
     def fn(p, s, v, m):
         return segstats_pallas(p, s, v, m, cfg.n_principals, cfg.n_shards,
                                rows=512, p_block=128, interpret=False)
-    _compile(fn, i32, i32, f32, f32)
+    _compile(fn, i32, i32, f32, f32, kernel=("segstats", "segstats"))
 
 
 def test_ddsketch_compiles_for_v5e(one_chip):
@@ -100,7 +124,8 @@ def test_ddsketch_compiles_for_v5e(one_chip):
     def fn(v, p, m):
         return grouped_update_pallas(cfg, v, p, m, pcfg.n_principals,
                                      rows=512, p_block=128, interpret=False)
-    _compile(fn, f32, i32, f32)
+    _compile(fn, f32, i32, f32,
+             kernel=("ddsketch_grouped_update", "ddsketch"))
 
 
 def test_hashshard_compiles_for_v5e(one_chip):
@@ -110,7 +135,7 @@ def test_hashshard_compiles_for_v5e(one_chip):
 
     def fn(b, n):
         return hashshard_pallas(b, n, 4, interpret=False)
-    _compile(fn, rows, lens)
+    _compile(fn, rows, lens, kernel=("hashshard", "hashshard"))
 
 
 @pytest.fixture
