@@ -231,8 +231,10 @@ def make_aggregate_step(cfg: PipelineConfig, mesh, dp_axes=("data",),
                         tp_axis="model", scatter_merge: bool = False):
     """Grouped DDSketch per principal x attribute through the DDSketch
     kernel's entry point (compiled on a TPU, its jnp reference on the
-    CPU). scatter_merge: reduce-scatter the sketch merge over the DP axes
-    (halves merge wire bytes; output principals shard over tp x dp)."""
+    CPU): one update per attribute, over all principal streams stacked
+    as (streams, N). scatter_merge: reduce-scatter the sketch merge over
+    the DP axes (halves merge wire bytes; output principals shard over
+    tp x dp)."""
     n_tp = mesh.shape[tp_axis]
     assert cfg.n_principals % n_tp == 0
     p_loc = cfg.n_principals // n_tp
@@ -246,15 +248,17 @@ def make_aggregate_step(cfg: PipelineConfig, mesh, dp_axes=("data",),
         p0 = jax.lax.axis_index(tp_axis) * p_loc
         state = dds.init(cfg.sketch, (p_loc, len(ATTRS)))
         vmask = valid.astype(jnp.float32)
+        lps, masks = [], []
+        for pid, m in _principal_streams(cfg, rows):
+            lp = pid - p0
+            sel = ((lp >= 0) & (lp < p_loc)).astype(jnp.float32)
+            lps.append(jnp.clip(lp, 0, p_loc - 1))
+            masks.append(m * sel * vmask)
+        lps, masks = jnp.stack(lps), jnp.stack(masks)     # (streams, N)
         for ai, attr in enumerate(ATTRS):
-            vals = rows[attr]
             sub = jax.tree.map(lambda s: s[:, ai], state)
-            for pid, m in _principal_streams(cfg, rows):
-                lp = pid - p0
-                sel = ((lp >= 0) & (lp < p_loc)).astype(jnp.float32)
-                sub = dd_ops.update_grouped(
-                    cfg.sketch, sub, vals, jnp.clip(lp, 0, p_loc - 1),
-                    p_loc, mask=m * sel * vmask)
+            sub = dd_ops.update_grouped(cfg.sketch, sub, rows[attr], lps,
+                                        p_loc, mask=masks)
             state = jax.tree.map(lambda s, ns: s.at[:, ai].set(ns), state, sub)
         if scatter_merge:
             return dds.merge_psum_scatter(state, dp_axes)

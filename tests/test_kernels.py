@@ -56,6 +56,130 @@ def test_ddsketch_kernel_property(n, p, scale, seed):
     _cmp_state(got, want, p)
 
 
+def _stacked_streams(rng, n, p, n_streams=5):
+    """Five principal streams over one value column, as the snapshot
+    aggregate step builds them: uid and gid slots, then directory levels
+    whose negative slots are clamped to 0 with mask 0. Some rows are
+    masked in every stream, some put the same principal in two streams,
+    and some values fall in the zero bucket."""
+    vals = rng.lognormal(8, 3, n).astype(np.float32)
+    vals[rng.random(n) < 0.1] = 0.0
+    pids = rng.integers(0, p, (n_streams, n)).astype(np.int32)
+    mask = np.ones((n_streams, n), np.float32)
+    raw = rng.integers(-1, p, (n_streams - 2, n))
+    pids[2:] = np.maximum(raw, 0)
+    mask[2:] = raw >= 0
+    twice = rng.random(n) < 0.2
+    pids[3, twice] = pids[2, twice]
+    mask[3, twice] = mask[2, twice]
+    mask[:, rng.random(n) < 0.1] = 0.0
+    return vals, pids, mask
+
+
+def _sequential_ref(cfg, vals, pids, mask, p):
+    from repro.core.sketches import ddsketch as dds
+    state = dds.init(cfg, (p,))
+    for pid, m in zip(pids, mask):
+        state = dds.update_grouped(cfg, state, jnp.asarray(vals),
+                                   jnp.asarray(pid), p, jnp.asarray(m))
+    return state
+
+
+def _assert_stacked_equal(got, want):
+    for k in ("counts", "zero_count", "count", "min", "max"):
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]),
+                                      err_msg=k)
+    np.testing.assert_allclose(np.asarray(got["total"]),
+                               np.asarray(want["total"]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("n,p,nb,rows,p_block", [
+    (700, 45, 512, 128, 32),        # P and N off their block sizes
+    (1200, 130, 256, 512, 128),     # P one past a block, N past two tiles
+    (512, 16, 2048, 512, 128)])     # the real bucket count, one tile
+def test_ddsketch_kernel_stacked_streams_match_sequential(n, p, nb, rows,
+                                                          p_block):
+    """S = 5 streams in one kernel call == five sequential grouped
+    updates: bucket counts, zero count, count, min and max bit-for-bit
+    (the one bf16 MXU pass is exact), total to float32 rounding."""
+    cfg = DDSketchConfig(n_buckets=nb)
+    vals, pids, mask = _stacked_streams(np.random.default_rng(n), n, p)
+    got = grouped_update_pallas(cfg, jnp.asarray(vals), jnp.asarray(pids),
+                                jnp.asarray(mask), p, rows=rows,
+                                p_block=p_block)
+    _assert_stacked_equal(got, _sequential_ref(cfg, vals, pids, mask, p))
+
+
+def test_ddsketch_kernel_one_stream_is_exact():
+    """(N,) ids and mask are S = 1: integer fields bit-identical to the
+    reference, with bucket ids spread over all 2,048 buckets."""
+    cfg = DDSketchConfig(n_buckets=2048)
+    rng = np.random.default_rng(11)
+    n, p = 1500, 200
+    vals = np.exp(rng.uniform(-3, 40, n)).astype(np.float32)
+    vals[:20] = 0.0
+    pids = rng.integers(0, p, n).astype(np.int32)
+    mask = (rng.random(n) > 0.1).astype(np.float32)
+    got = grouped_update_pallas(cfg, jnp.asarray(vals), jnp.asarray(pids),
+                                jnp.asarray(mask), p)
+    _assert_stacked_equal(got, _sequential_ref(cfg, vals, pids[None],
+                                               mask[None], p))
+
+
+def test_ddsketch_ops_stacked_streams():
+    """The entry point with (S, N) ids: the kernel path (interpret mode)
+    and the CPU form (the reference once per stream) agree, onto a
+    non-empty state, and mask=None means every stream is present."""
+    from repro.core.sketches import ddsketch as dds
+    from repro.kernels.ddsketch import ops as dd_ops
+    cfg = DDSketchConfig(n_buckets=512)
+    p = 40
+    vals, pids, mask = _stacked_streams(np.random.default_rng(5), 600, p)
+    vals, pids, mask = map(jnp.asarray, (vals, pids, mask))
+    state = dds.update_grouped(cfg, dds.init(cfg, (p,)), vals[::-1],
+                               pids[0], p)
+    want = dd_ops.update_grouped(cfg, state, vals, pids, p, mask)
+    _assert_stacked_equal(dd_ops.kernel_update_grouped(
+        cfg, state, vals, pids, p, mask, interpret=True), want)
+    _assert_stacked_equal(
+        dd_ops.update_grouped(cfg, state, vals, pids, p),
+        dd_ops.update_grouped(cfg, state, vals, pids, p,
+                              jnp.ones(pids.shape, jnp.float32)))
+
+
+def test_aggregate_step_kernel_path_matches_local(monkeypatch):
+    """snapshot.make_aggregate_step through the kernel (interpret mode,
+    one call per attribute over the five stacked streams) == the
+    reference ``aggregate_local`` (twenty sequential jnp updates)."""
+    import functools
+
+    import jax
+
+    from repro.core import snapshot as snap
+    from repro.core.metadata import synth_filesystem
+    from repro.kernels.ddsketch import ops as dd_ops
+    from repro.launch.mesh import make_mesh
+    calls = []
+    kernel = functools.partial(dd_ops.kernel_update_grouped, interpret=True)
+
+    def counted(cfg, state, values, pids, n, mask):
+        calls.append(pids.shape)
+        return kernel(cfg, state, values, pids, n, mask)
+    monkeypatch.setattr(dd_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(dd_ops, "kernel_update_grouped", counted)
+    table = synth_filesystem(1500, n_users=16, n_groups=8, seed=2)
+    pcfg = snap.PipelineConfig(n_users=16, n_groups=8, n_dirs=40,
+                               sketch=snap.dds.DDSketchConfig(n_buckets=512))
+    rows_np, valid_np = snap.pad_rows(snap.preprocess(table, pcfg), 512)
+    rows = {k: jnp.asarray(v) for k, v in rows_np.items()}
+    valid = jnp.asarray(valid_np)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    got = jax.jit(snap.make_aggregate_step(pcfg, mesh))(rows, valid)
+    levels = pcfg.dir_max - pcfg.dir_min + 1
+    assert calls == [(2 + levels, len(valid_np))] * len(snap.ATTRS)
+    _assert_stacked_equal(got, snap.aggregate_local(pcfg, rows, valid))
+
+
 def test_hashshard_kernel_matches_host():
     strings = [f"/fs/project{i}/dir{i % 7}/file_{i}.dat" for i in range(300)]
     rows, lens = encode_strings(strings, width=64)

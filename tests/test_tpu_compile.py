@@ -66,18 +66,24 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _custom_calls(compiled):
+    """The Pallas custom-call lines of a compiled program's HLO, printed
+    with operand shapes as the profiler's op names carry them."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+            and "custom-call(" in ln]
+
+
 def _compile(fn, *shapes, kernel):
     """Compile ``fn`` for the chip; its one Pallas custom call is named
     ``kernel`` and classified as ``kernel`` (the ``name=`` given) by
     the trace reduction, from the HLO text with operand shapes the
     profiler's op names carry."""
-    from jax._src.lib import xla_client
     compiled = jax.jit(fn).lower(*shapes).compile()
-    opts = xla_client._xla.HloPrintOptions.short_parsable()
-    opts.print_operand_shape = True
-    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
-             and "custom-call(" in ln]
+    calls = _custom_calls(compiled)
     assert len(calls) == 1, calls
     name, kind = kernel
     assert re.match(rf"\s*(ROOT\s+)?%?{name}(\.\d+)?\s*=", calls[0]), \
@@ -118,14 +124,61 @@ def test_segstats_compiles_for_v5e(one_chip):
 def test_ddsketch_compiles_for_v5e(one_chip):
     pcfg = PipelineConfig()
     cfg = DDSketchConfig(n_buckets=2048)
-    i32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.int32, sharding=one_chip)
-    f32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.float32, sharding=one_chip)
+    streams = 2 + pcfg.dir_max - pcfg.dir_min + 1
+    i32 = jax.ShapeDtypeStruct((streams, BATCH_ROWS), jnp.int32,
+                               sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((streams, BATCH_ROWS), jnp.float32,
+                               sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.float32, sharding=one_chip)
 
     def fn(v, p, m):
         return grouped_update_pallas(cfg, v, p, m, pcfg.n_principals,
-                                     rows=512, p_block=128, interpret=False)
-    _compile(fn, f32, i32, f32,
+                                     interpret=False)
+    _compile(fn, vals, i32, f32,
              kernel=("ddsketch_grouped_update", "ddsketch"))
+
+
+def test_aggregate_step_compiles_for_v5e(topo, monkeypatch):
+    """The snapshot aggregate step at the rescan cell's shapes (one
+    262,144-row chunk, the cell's pipeline configuration) makes one
+    DDSketch kernel call per attribute, each named and classified as
+    the benchmark's trace reduction expects."""
+    import json
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import deploy
+    from repro.core import snapshot as snap
+    from repro.kernels.ddsketch import ops as dd_ops
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "configs",
+                           "scan_refresh_4m.json")) as f:
+        cell = json.load(f)
+    pcfg = deploy.pipeline_config(cell["pipeline"])
+    n = cell["index"]["chunk"]
+    # a described chip: steer the entry point to its TPU form here
+    monkeypatch.setattr(dd_ops, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    levels = pcfg.dir_max - pcfg.dir_min + 1
+
+    def s(shape, dt):
+        spec = P("data", *([None] * (len(shape) - 1)))
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+    rows = {k: s((n,), jnp.int32) for k in
+            ("uid_slot", "gid_slot", "shard_id", "uid", "gid", "mode",
+             "type")}
+    rows.update({k: s((n,), jnp.float32) for k in snap.ATTRS})
+    rows["dir_slots"] = s((n, levels), jnp.int32)
+    rows["path_hash"] = s((n,), jnp.uint32)
+    compiled = jax.jit(snap.make_aggregate_step(pcfg, mesh)).lower(
+        rows, s((n,), jnp.bool_)).compile()
+    calls = _custom_calls(compiled)
+    assert len(calls) == len(snap.ATTRS), [c[:120] for c in calls]
+    for call in calls:
+        assert re.match(r"\s*(ROOT\s+)?%?ddsketch_grouped_update(\.\d+)?\s*=",
+                        call), call[:120]
+        assert trace_reduce.kernel_of(call) == "ddsketch"
 
 
 def test_hashshard_compiles_for_v5e(one_chip):
