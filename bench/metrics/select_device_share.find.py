@@ -1,0 +1,14 @@
+"""Share of the window inside the program's ``query.select.device`` span:
+the program upload, the predicate kernel and the bitmap readback
+(``predeval_words`` in ``QueryEngine._kernel_pass``), summed over
+shards. Read from the window's delta of
+``span_seconds_total{span=query.select.device}``, in percent; None where
+the program has no such span."""
+
+KEY = "span_seconds_total|span=query.select.device"
+
+
+def read(ctx):
+    s = ctx["counters"].get(KEY, 0.0)
+    w = ctx["window_s"]
+    return 100.0 * s / w if s > 0 and w > 0 else None

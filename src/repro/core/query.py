@@ -232,6 +232,18 @@ class QueryEngine:
             "query_discovery_fallback_total",
             "planner declines by reason",
             labels=("reason",))
+        self._c_candidates = self.telemetry.counter(
+            "query_candidates_total",
+            "rows a query kept at each stage: the kernel's bitmap, the "
+            "exact verify, the name match",
+            labels=("query", "stage"))
+        # stage spans of the kernel route and the find's name match,
+        # bound once; each is a leaf
+        self._span_pack = self.telemetry.span("query.arena.pack")
+        self._span_device = self.telemetry.span("query.select.device")
+        self._span_unpack = self.telemetry.span("query.select.unpack")
+        self._span_verify = self.telemetry.span("query.select.verify")
+        self._span_name = self.telemetry.span("query.find.name")
 
     @property
     def now(self) -> float:
@@ -265,7 +277,7 @@ class QueryEngine:
         "owned_by_deleted_users", "past_retention", "directories_over",
         "storage_by_project", "quota_pressure", "most_small_files",
         "per_user_usage", "dir_size_percentile", "top_storage_users",
-        "du", "subtree_summary", "hot_directories",
+        "du", "subtree_summary", "hot_directories", "find",
     })
 
     def query(self, name: str, *args, **kw) -> Dict:
@@ -359,9 +371,39 @@ class QueryEngine:
         hit = self._arena_cache.get(si)
         if hit is not None and hit[0] == key:
             return hit[1]
-        arena = pk_ops.pack_arena(sh.columns, sh.alive, n)
+        with self._span_pack:              # host pack and H2D copy
+            arena = pk_ops.pack_arena(sh.columns, sh.alive, n)
         self._arena_cache[si] = (key, arena)
         return arena
+
+    def _kernel_pass(self, batch: Sequence[Tuple[str, List, dict]]
+                     ) -> Tuple[List[np.ndarray], int]:
+        """One fused kernel pass per shard over the stacked programs of
+        ``batch`` (``(query name, predicate list, compiled program)``
+        each), then per program the bitmap's candidate slots, exact-
+        verified against the shard's arenas. Returns each program's
+        verified paths (shard-major, i.e. scan order) and the summed
+        candidate count."""
+        progs = pk_ref.stack_programs([p for _, _, p in batch])
+        parts: List[List[np.ndarray]] = [[] for _ in batch]
+        total = 0
+        for si, sh in enumerate(self._index_shards()):
+            n = _shard_rows(sh)
+            arena = self._shard_arena(si, sh, n)
+            with self._span_device:        # program H2D, kernel, readback
+                words = pk_ops.predeval_words(arena, progs)
+            for j, (qname, preds, _) in enumerate(batch):
+                with self._span_unpack:
+                    cand = pk_ops.bitmap_slots(words, j, n)
+                with self._span_verify:
+                    got = disc.verify_select(sh.alive, sh.columns,
+                                             sh.paths, cand, preds)
+                self._c_candidates.labels(qname, "kernel").inc(len(cand))
+                self._c_candidates.labels(qname, "verified").inc(len(got))
+                total += len(cand)
+                parts[j].append(got)
+        return [p[0] if len(p) == 1 else np.concatenate(p)
+                for p in parts], total
 
     def _kernel_select(self, qname: str,
                        preds: Sequence[Tuple[str, str, object]]
@@ -381,21 +423,12 @@ class QueryEngine:
             self.last_plan = dict(plan, reason=(
                 f"{plan.get('reason', '')}; program inexpressible"))
             return None
-        progs = pk_ref.stack_programs([prog])
         why = (self.last_plan or {}).get("reason", "")
-        parts, total = [], 0
-        for si, sh in enumerate(self._index_shards()):
-            n = _shard_rows(sh)
-            arena = self._shard_arena(si, sh, n)
-            words = pk_ops.predeval_words(arena, progs)
-            cand = pk_ops.bitmap_slots(words, 0, n)
-            total += len(cand)
-            parts.append(disc.verify_select(sh.alive, sh.columns,
-                                            sh.paths, cand, preds))
+        (got,), total = self._kernel_pass([(qname, preds, prog)])
         self.last_plan = {"query": qname, "route": "kernel",
                           "reason": f"fused kernel ({why})",
                           "candidates": total}
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+        return got
 
     def _scan_select(self, preds: Sequence[Tuple[str, str, object]]
                      ) -> np.ndarray:
@@ -457,21 +490,10 @@ class QueryEngine:
                     batch.append((i, preds, prog))
         batched = {i for i, _, _ in batch}
         if batch:
-            progs = pk_ref.stack_programs([p for _, _, p in batch])
-            parts: Dict[int, List] = {i: [] for i in batched}
-            total = 0
-            for si, sh in enumerate(self._index_shards()):
-                n = _shard_rows(sh)
-                arena = self._shard_arena(si, sh, n)
-                words = pk_ops.predeval_words(arena, progs)
-                for j, (i, preds, _) in enumerate(batch):
-                    cand = pk_ops.bitmap_slots(words, j, n)
-                    total += len(cand)
-                    parts[i].append(disc.verify_select(
-                        sh.alive, sh.columns, sh.paths, cand, preds))
-            for i in batched:
-                p = parts[i]
-                results[i] = p[0] if len(p) == 1 else np.concatenate(p)
+            got, total = self._kernel_pass(
+                [(specs[i][0], preds, prog) for i, preds, prog in batch])
+            for (i, _, _), res in zip(batch, got):
+                results[i] = res
             self.last_plan = {"query": "select_many", "route": "kernel",
                               "batched": len(batch),
                               "fallback": len(specs) - len(batch),
@@ -537,6 +559,35 @@ class QueryEngine:
         paths = self.primary.live_paths()
         return paths[[i for i, p in enumerate(paths)
                       if fnmatch.fnmatchcase(p, pattern)]]
+
+    def find(self, name: str, size: float, newer: float) -> np.ndarray:
+        """``find -name NAME -size SIZEc -newer T`` (GNU find; IO500's
+        find phase): live records whose basename (the path after its
+        last ``/``) matches the glob ``name`` under
+        ``fnmatch.fnmatchcase``, whose stored ``size`` equals ``size``
+        and whose stored ``mtime`` is strictly after ``newer``, in scan
+        order. Both attributes compare on the float32 columns with the
+        arguments rounded to float32 (DESIGN.md §13.5, §13.7): ``size``
+        is the one-ulp open interval around ``f32(size)``, which holds
+        exactly that value. The attribute part runs the route cascade
+        (``_pred_query``: discovery -> kernel -> scan); the name match
+        then runs on the verified paths."""
+        s = np.float32(size)
+        if not np.isfinite(s):
+            raise ValueError(f"find: size must be finite, got {size!r}")
+        preds = [("size", "gt", float(np.nextafter(s, np.float32(-np.inf)))),
+                 ("size", "lt", float(np.nextafter(s, np.float32(np.inf)))),
+                 ("mtime", "gt", float(newer))]
+        paths = self._pred_query("find", preds)
+        with self._span_name:
+            match = re.compile(fnmatch.translate(name)).match
+            # match at the basename's offset: no slice per path
+            got = paths[[i for i, p in enumerate(paths)
+                         if match(p, p.rfind("/") + 1)]]
+        self._c_candidates.labels("find", "matched").inc(len(got))
+        self.last_plan = dict(self.last_plan or {}, verified=len(paths),
+                              matched=len(got))
+        return got
 
     def world_writable(self) -> np.ndarray:
         """Table I "world-writable files" (security audit): mode & 0o002.

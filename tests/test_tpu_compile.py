@@ -110,6 +110,29 @@ def test_predeval_compiles_for_v5e(one_chip, has_set):
              s((ks, SET_CAP), jnp.int32), kernel=("predeval", "predeval"))
 
 
+@pytest.mark.parametrize("rows", [1 << 21, 1 << 22])
+def test_predeval_compiles_at_find_arena_shapes(one_chip, rows):
+    """The IO500 find cell's arenas (8,388,608 records over 4 shards pad
+    to 2^21 or 2^22 rows): one program (K = 1), no set."""
+    from repro.kernels.predeval import ref as pk_ref
+    progs = pk_ref.stack_programs([pk_ref.compile_program(
+        [("size", "gt", 3900.5), ("size", "lt", 3901.5),
+         ("mtime", "gt", 1.7e9)])])
+    assert progs.k_pad == 1 and not progs.has_set
+
+    def s(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def fn(*a):
+        return predeval(*a, has_set=False)
+    _compile(fn, s(jax.ShapeDtypeStruct((3, rows), jnp.float32)),
+             s(jax.ShapeDtypeStruct((3, rows), jnp.int32)),
+             s(jax.ShapeDtypeStruct((rows,), jnp.int32)),
+             *(s(a) for a in (progs.ops, progs.lo, progs.hi, progs.msk,
+                              progs.setrows, progs.setcol, progs.setvals)),
+             kernel=("predeval", "predeval"))
+
+
 def test_segstats_compiles_for_v5e(one_chip):
     cfg = PipelineConfig()
     i32 = jax.ShapeDtypeStruct((BATCH_ROWS,), jnp.int32, sharding=one_chip)
