@@ -28,12 +28,14 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import discovery as disc
 from repro.core import hierarchy as hier
 from repro.core.index import AggregateIndex, PrimaryIndex
 from repro.core.telemetry import resolve as _resolve_tel
+from repro.kernels.predeval import names as pk_names
 from repro.kernels.predeval import ops as pk_ops
 from repro.kernels.predeval import ref as pk_ref
 
@@ -217,6 +219,9 @@ class QueryEngine:
         #: are atomic under the GIL — concurrent readers at worst
         #: rebuild the same immutable slab twice.
         self._arena_cache: Dict[int, Tuple] = {}
+        #: the find's basename columns, cached like the arenas and
+        #: built only when a find with a usable literal arrives
+        self._names_cache: Dict[int, Tuple] = {}
         # per-thread plan records: concurrent readers sharing one
         # engine (the serving tier admits N at once) must not observe
         # each other's routing decisions
@@ -235,8 +240,13 @@ class QueryEngine:
         self._c_candidates = self.telemetry.counter(
             "query_candidates_total",
             "rows a query kept at each stage: the kernel's bitmap, the "
-            "exact verify, the name match",
+            "name prefilter, the exact verify, the name match",
             labels=("query", "stage"))
+        self._c_prefilter = self.telemetry.counter(
+            "query_name_prefilter_total",
+            "finds by where their basename prefilter ran: on the device "
+            "(kernel route, a literal in the glob) or on the host alone",
+            labels=("route",))
         # stage spans of the kernel route and the find's name match,
         # bound once; each is a leaf
         self._span_pack = self.telemetry.span("query.arena.pack")
@@ -376,34 +386,68 @@ class QueryEngine:
         self._arena_cache[si] = (key, arena)
         return arena
 
-    def _kernel_pass(self, batch: Sequence[Tuple[str, List, dict]]
-                     ) -> Tuple[List[np.ndarray], int]:
+    def _shard_names(self, si: int, sh, arena):
+        """The (shard, epoch) basename column for the find's prefilter,
+        padded to the arena's rows and cached like it."""
+        key = (int(sh.mutation_epoch), arena.n)
+        hit = self._names_cache.get(si)
+        if hit is not None and hit[0] == key:
+            return hit[1]
+        with self._span_pack:              # host pack and H2D copy
+            col = pk_names.pack_names(sh.paths, arena.n, arena.n_pad)
+        self._names_cache[si] = (key, col)
+        return col
+
+    def _kernel_pass(self, batch: Sequence[Tuple[str, List, dict, object]]
+                     ) -> Tuple[List[np.ndarray], Dict[str, int]]:
         """One fused kernel pass per shard over the stacked programs of
-        ``batch`` (``(query name, predicate list, compiled program)``
-        each), then per program the bitmap's candidate slots, exact-
-        verified against the shard's arenas. Returns each program's
-        verified paths (shard-major, i.e. scan order) and the summed
-        candidate count."""
-        progs = pk_ref.stack_programs([p for _, _, p in batch])
+        ``batch`` (``(query name, predicate list, compiled program, name
+        literals)`` each), then per program the bitmap's candidate
+        slots, exact-verified against the shard's arenas. An entry whose
+        literals are not None (``names.name_literals``'s operand) also
+        takes the basename prefilter, ANDed into its bitmap on the
+        device before the one readback. Returns each program's verified
+        paths (shard-major, i.e. scan order) and the summed counts:
+        ``candidates`` (the predicate bitmaps alone), and for the named
+        entries ``name_device`` (rows left after the AND) and
+        ``name_keep`` (keep-flagged rows of the shards)."""
+        progs = pk_ref.stack_programs([p for _, _, p, _ in batch])
+        named = [(j, lits) for j, (_, _, _, lits) in enumerate(batch)
+                 if lits is not None]
         parts: List[List[np.ndarray]] = [[] for _ in batch]
-        total = 0
+        stats = {"candidates": 0, "name_device": 0, "name_keep": 0}
         for si, sh in enumerate(self._index_shards()):
             n = _shard_rows(sh)
             arena = self._shard_arena(si, sh, n)
-            with self._span_device:        # program H2D, kernel, readback
-                words = pk_ops.predeval_words(arena, progs)
-            for j, (qname, preds, _) in enumerate(batch):
+            col = self._shard_names(si, sh, arena) if named else None
+            # program H2D, kernel, name prefilter, readback
+            with self._span_device:
+                dev = pk_ops.predeval_device(arena, progs)
+                kept = []
+                for j, lits in named:
+                    dev, k = pk_names.and_row(dev, j, col, lits)
+                    kept.append(k)
+                words, kept = jax.device_get((dev, kept))
+            kernel = {j: int(k) for (j, _), k in zip(named, kept)}
+            if named:
+                stats["name_keep"] += col.n_keep
+            for j, (qname, preds, _, _) in enumerate(batch):
                 with self._span_unpack:
                     cand = pk_ops.bitmap_slots(words, j, n)
                 with self._span_verify:
                     got = disc.verify_select(sh.alive, sh.columns,
                                              sh.paths, cand, preds)
-                self._c_candidates.labels(qname, "kernel").inc(len(cand))
+                k = kernel.get(j, len(cand))
+                self._c_candidates.labels(qname, "kernel").inc(k)
+                if j in kernel:
+                    self._c_candidates.labels(
+                        qname, "name_device").inc(len(cand))
+                    stats["name_device"] += len(cand)
                 self._c_candidates.labels(qname, "verified").inc(len(got))
-                total += len(cand)
+                stats["candidates"] += k
                 parts[j].append(got)
         return [p[0] if len(p) == 1 else np.concatenate(p)
-                for p in parts], total
+                for p in parts], stats
 
     def _kernel_select(self, qname: str,
                        preds: Sequence[Tuple[str, str, object]]
@@ -414,7 +458,9 @@ class QueryEngine:
         primary arenas — the discovery index's superset discipline, so
         the result is byte-identical to the scan path in scan order.
         None -> inexpressible program or kernels disabled (caller
-        scans)."""
+        scans). Inside ``find`` this thread's basename literal operand
+        (``names.name_literals``) rides along and is prefiltered on the
+        device with the program."""
         if not self.use_kernels:
             return None
         prog = pk_ref.compile_program(preds)
@@ -424,10 +470,16 @@ class QueryEngine:
                 f"{plan.get('reason', '')}; program inexpressible"))
             return None
         why = (self.last_plan or {}).get("reason", "")
-        (got,), total = self._kernel_pass([(qname, preds, prog)])
-        self.last_plan = {"query": qname, "route": "kernel",
-                          "reason": f"fused kernel ({why})",
-                          "candidates": total}
+        name_lits = getattr(self._plan_tls, "name_lits", None)
+        (got,), stats = self._kernel_pass([(qname, preds, prog, name_lits)])
+        plan = {"query": qname, "route": "kernel",
+                "reason": f"fused kernel ({why})",
+                "candidates": stats["candidates"]}
+        if name_lits is not None:
+            plan.update(name_prefilter="device",
+                        name_device=stats["name_device"],
+                        name_keep=stats["name_keep"])
+        self.last_plan = plan
         return got
 
     def _scan_select(self, preds: Sequence[Tuple[str, str, object]]
@@ -490,14 +542,15 @@ class QueryEngine:
                     batch.append((i, preds, prog))
         batched = {i for i, _, _ in batch}
         if batch:
-            got, total = self._kernel_pass(
-                [(specs[i][0], preds, prog) for i, preds, prog in batch])
+            got, stats = self._kernel_pass(
+                [(specs[i][0], preds, prog, None)
+                 for i, preds, prog in batch])
             for (i, _, _), res in zip(batch, got):
                 results[i] = res
             self.last_plan = {"query": "select_many", "route": "kernel",
                               "batched": len(batch),
                               "fallback": len(specs) - len(batch),
-                              "candidates": total}
+                              "candidates": stats["candidates"]}
         for i, (name, args, kw) in enumerate(specs):
             if i in batched:
                 continue
@@ -570,23 +623,36 @@ class QueryEngine:
         arguments rounded to float32 (DESIGN.md §13.5, §13.7): ``size``
         is the one-ulp open interval around ``f32(size)``, which holds
         exactly that value. The attribute part runs the route cascade
-        (``_pred_query``: discovery -> kernel -> scan); the name match
-        then runs on the verified paths."""
+        (``_pred_query``: discovery -> kernel -> scan). On the kernel
+        route the glob's literal runs prefilter the basenames on the
+        device, ANDed into the program's bitmap (``names.py``: a superset
+        of the matches); the exact glob then runs on the verified
+        paths, so every route returns the same answer."""
         s = np.float32(size)
         if not np.isfinite(s):
             raise ValueError(f"find: size must be finite, got {size!r}")
         preds = [("size", "gt", float(np.nextafter(s, np.float32(-np.inf)))),
                  ("size", "lt", float(np.nextafter(s, np.float32(np.inf)))),
                  ("mtime", "gt", float(newer))]
-        paths = self._pred_query("find", preds)
+        # the kernel route reads the literals from this thread's state,
+        # so the cascade's signatures stay those of every predicate query
+        self._plan_tls.name_lits = pk_names.name_literals(
+            disc.glob_literals(name))
+        try:
+            paths = self._pred_query("find", preds)
+        finally:
+            self._plan_tls.name_lits = None
+        plan = self.last_plan or {}
+        route = plan.get("name_prefilter", "host")
+        self._c_prefilter.labels(route).inc()
         with self._span_name:
             match = re.compile(fnmatch.translate(name)).match
             # match at the basename's offset: no slice per path
             got = paths[[i for i, p in enumerate(paths)
                          if match(p, p.rfind("/") + 1)]]
         self._c_candidates.labels("find", "matched").inc(len(got))
-        self.last_plan = dict(self.last_plan or {}, verified=len(paths),
-                              matched=len(got))
+        self.last_plan = dict(plan, name_prefilter=route,
+                              verified=len(paths), matched=len(got))
         return got
 
     def world_writable(self) -> np.ndarray:

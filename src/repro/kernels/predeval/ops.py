@@ -95,16 +95,20 @@ def _jitted(has_set: bool, use_pallas: bool):
     return jax.jit(fn)
 
 
-def predeval_words(arena: Arena, progs: ref.Programs) -> np.ndarray:
-    """(k_pad, n_pad/32) uint32 packed bitmaps for the program batch —
-    one fused read of the arena regardless of K."""
+def predeval_device(arena: Arena, progs: ref.Programs) -> jax.Array:
+    """(k_pad, n_pad/32) uint32 packed bitmaps for the program batch,
+    left on the device — one fused read of the arena regardless of K."""
     fn = _jitted(progs.has_set, on_tpu())
-    out = fn(arena.fcols, arena.icols, arena.alive,
-             jnp.asarray(progs.ops), jnp.asarray(progs.lo),
-             jnp.asarray(progs.hi), jnp.asarray(progs.msk),
-             jnp.asarray(progs.setrows), jnp.asarray(progs.setcol),
-             jnp.asarray(progs.setvals))
-    return np.asarray(out)
+    return fn(arena.fcols, arena.icols, arena.alive,
+              jnp.asarray(progs.ops), jnp.asarray(progs.lo),
+              jnp.asarray(progs.hi), jnp.asarray(progs.msk),
+              jnp.asarray(progs.setrows), jnp.asarray(progs.setcol),
+              jnp.asarray(progs.setvals))
+
+
+def predeval_words(arena: Arena, progs: ref.Programs) -> np.ndarray:
+    """``predeval_device``'s bitmaps, read back to the host."""
+    return np.asarray(predeval_device(arena, progs))
 
 
 def bitmap_slots(words: np.ndarray, k: int, n: int) -> np.ndarray:

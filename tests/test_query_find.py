@@ -2,11 +2,12 @@
 (IO500's find phase) over the index, against a brute force over
 ``live()`` on every route (fused kernel, scan, discovery fresh and
 stale), on monolithic and 4-shard indexes; through ``QueryService`` and
-its result cache; and the stage spans and candidate counts of its
-kernel route."""
+its result cache; the stage spans and candidate counts of its kernel
+route; and the basename prefilter that route runs on the device."""
 import fnmatch
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -226,7 +227,175 @@ def test_find_spans_are_leaves_and_counts_shrink():
     counts = {s["labels"]["stage"]: s["value"]
               for s in snap["query_candidates_total"]["series"]
               if s["labels"]["query"] == "find"}
-    assert counts["kernel"] >= counts["verified"] >= counts["matched"]
+    assert counts["kernel"] >= counts["name_device"] \
+        >= counts["verified"] >= counts["matched"]
+    assert counts["name_device"] == q.last_plan["name_device"]
     assert counts["matched"] == len(got)
     assert counts["verified"] == q.last_plan["verified"]
     assert counts["kernel"] == q.last_plan["candidates"]
+
+
+#: basenames beside mdtest's that the prefilter has to get right: a
+#: literal at a basename's first and last byte, one that only a
+#: directory holds, a basename longer than the column (kept by its
+#: flag) and non-ASCII ones (substrings of strict UTF-8); the sharded
+#: slot map holds no surrogate escape, so the kernel-level test below
+#: covers that flag
+ODD_NAMES = ["ax01", "bxc", "a.c", "abc", "01file", "data.h5", "x.h5.bak",
+             "file.mdtest.0.01" + "q" * 30, "résumé01.h5", "数据01.h5",
+             "日本語のファイル名01", "c"]
+PREFILTER_CASES = [
+    ("*01*", "device"), ("file.*", "device"), ("*.h5", "device"),
+    ("?x*", "device"), ("[ab]*c", "device"), ("*", "host"),
+    ("file.mdtest.1*", "device"),      # a literal at the first byte
+    ("*.mdtest.2.7", "device"),        # and at the last byte
+    ("*09.01*", "device"),             # only in a directory name
+    ("*数据*", "device"), ("*é*", "device"), ("*qqqq*", "device"),
+]
+
+
+def odd_namespace():
+    paths, fields = mdtest_namespace(n_ranks=4, easy=10, hard=40, seed=11)
+    extra = [f"/io500/2024.11.04-09.01.37/odd/{b}" for b in ODD_NAMES]
+    n = len(extra)
+    t = np.float32(STAMP + 500)
+    more = {k: np.full(n, v[0], v.dtype) for k, v in fields.items()}
+    more.update(size=np.full(n, 3901.0, np.float32),
+                mtime=np.full(n, t, np.float32))
+    return (np.concatenate([paths, np.asarray(extra, object)]),
+            {k: np.concatenate([v, more[k]]) for k, v in fields.items()})
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("name,route", PREFILTER_CASES)
+def test_prefilter_route_equals_brute_force(layout, name, route):
+    """The kernel route with the basename prefilter returns the brute
+    force's answer in scan order; a glob with no literal leaves the
+    match to the host."""
+    paths, fields = odd_namespace()
+    tel = Telemetry()
+    idx = LAYOUTS[layout]()
+    idx.upsert_batch(paths, fields, np.ones(len(paths), np.int64))
+    q = QueryEngine(idx, AggregateIndex(), now=STAMP, telemetry=tel)
+    got = q.find(name, 3901, STAMP)
+    want = brute(idx, name, 3901, STAMP)
+    assert list(got) == list(want)
+    plan = q.last_plan
+    assert plan["route"] == "kernel" and plan["name_prefilter"] == route
+    snap = tel.snapshot(traces=False)["metrics"]
+    routes = {s["labels"]["route"]: s["value"] for s in
+              snap["query_name_prefilter_total"]["series"]}
+    assert routes == {route: 1}
+    if route == "device":
+        assert plan["name_keep"] == 1     # the long basename
+        assert plan["candidates"] >= plan["name_device"] \
+            >= plan["verified"] >= plan["matched"]
+    if name == "*09.01*":
+        # only the flagged row passes the device test
+        assert len(want) == 0 and plan["name_device"] == 1
+    if name in ("*01*", "*数据*", "*é*", "*qqqq*"):
+        assert len(want) > 0
+
+
+def test_prefilter_words_equal_a_numpy_reference():
+    """The packed prefilter words over a random column are, bit for bit
+    in ``unpack_bits`` order, ``all(literal in basename) or flag``;
+    after the AND the arena's padding rows are clear."""
+    from repro.kernels.predeval import names as pk_names
+    from repro.kernels.predeval import ref as pk_ref
+    rng = np.random.default_rng(4)
+    alphabet = list("ab01./") + ["é", "数"]
+    n, n_pad = 3000, 4096
+    base = ["".join(rng.choice(alphabet, rng.integers(0, 40)))
+            .replace("/", "") for _ in range(n)]
+    base[:3] = ["caf\udcffab", "ab" * 17, ""]
+    paths = np.asarray([f"/d{i % 7}ab/{b}" for i, b in enumerate(base)],
+                       object)
+    col = pk_names.pack_names(paths, n, n_pad)
+    enc = [b.encode("utf-8", "surrogatepass") for b in base]
+    flag = np.asarray([len(e) > pk_names.NAME_BYTES or "\udcff" in b
+                       for b, e in zip(base, enc)])
+    assert col.n_keep == flag.sum() >= 2
+    pred = np.zeros((1, n_pad), bool)
+    pred[0, :n] = True                 # the padding rows are dead
+    dev = jax.numpy.asarray(pk_ref.pack_words(pred))
+    for lits in (["01"], ["ab", "0"], ["a.b"], ["é"], ["数0", "b"]):
+        want = np.asarray([all(s.encode() in e for s in lits) or f
+                           for e, f in zip(enc, flag)])
+        op = pk_names.name_literals(lits)
+        words = np.asarray(jax.jit(pk_names.prefilter_words)(
+            col.names, col.keep, *op))
+        assert np.array_equal(pk_ref.unpack_bits(words, n), want), lits
+        anded, kept = pk_names.and_row(dev, 0, col, op)
+        bits = pk_ref.unpack_bits(np.asarray(anded)[0], n_pad)
+        assert np.array_equal(bits[:n], want) and not bits[n:].any()
+        assert int(kept) == n
+    # a path holding NUL leaves no byte boundary to trust: all kept
+    col = pk_names.pack_names(np.asarray(["/a/b\0c", "/d/e"], object), 2, 32)
+    assert col.n_keep == 2
+
+
+def test_new_timestamps_and_patterns_of_one_bucket_do_not_compile():
+    """Two finds at new timestamps, and two patterns whose literals fall
+    in one (count, length) bucket, reuse the compiled passes."""
+    from repro.kernels.predeval import names as pk_names
+    from repro.kernels.predeval import ops as pk_ops
+    paths, fields = mdtest_namespace(seed=6)
+    idx = ShardedPrimaryIndex(4)
+    idx.upsert_batch(paths, fields, np.ones(len(paths), np.int64))
+    q = QueryEngine(idx, AggregateIndex(), now=STAMP)
+    q.find("*01*", 3901, STAMP)
+    sizes = (pk_names._and_row._cache_size(),
+             pk_ops._jitted(False, False)._cache_size())
+    for name, newer in (("*01*", STAMP - 1), ("*01*", STAMP - 2),
+                        ("*23*", STAMP), ("*7.1*", STAMP - 3)):
+        got = q.find(name, 3901, newer)
+        assert list(got) == list(brute(idx, name, 3901, newer))
+        assert q.last_plan["name_prefilter"] == "device"
+    assert (pk_names._and_row._cache_size(),
+            pk_ops._jitted(False, False)._cache_size()) == sizes
+
+
+def test_predicate_queries_build_no_name_column():
+    """``_kernel_select`` and ``select_many`` pack and cache the arenas
+    alone; the find then reuses those arenas and adds the names."""
+    paths, fields = mdtest_namespace(seed=7)
+    tel = Telemetry()
+    idx = ShardedPrimaryIndex(4, telemetry=tel)
+    idx.upsert_batch(paths, fields, np.ones(len(paths), np.int64))
+    q = QueryEngine(idx, AggregateIndex(), now=STAMP + 3600, telemetry=tel)
+    q.world_writable()
+    assert q.last_plan["route"] == "kernel"
+    assert "name_prefilter" not in q.last_plan
+    q.select_many([("past_retention", (60,), {}),
+                   ("large_cold_files", (100, 60), {})])
+    assert q.last_plan["route"] == "kernel" and not q._names_cache
+    arenas = {si: hit for si, hit in q._arena_cache.items()}
+    assert len(arenas) == 4
+    q.find("*01*", 3901, STAMP)
+    assert q._arena_cache == arenas and len(q._names_cache) == 4
+    for si, (key, col) in q._names_cache.items():
+        assert key == arenas[si][0] and col.n_pad == arenas[si][1].n_pad
+    snap = tel.snapshot(traces=False)["metrics"]
+    assert "name_device" not in {
+        s["labels"]["stage"] for s in snap["query_candidates_total"]["series"]
+        if s["labels"]["query"] != "find"}
+
+
+@pytest.mark.parametrize("route", ["scan", "discovery"])
+def test_other_routes_leave_the_match_to_the_host(route):
+    paths, fields = mdtest_namespace(seed=8)
+    tel = Telemetry()
+    idx = ShardedPrimaryIndex(4, telemetry=tel)
+    idx.upsert_batch(paths, fields, np.ones(len(paths), np.int64))
+    if route == "discovery":
+        idx.attach_discovery()
+    q = QueryEngine(idx, AggregateIndex(), now=STAMP, telemetry=tel,
+                    use_kernels=route != "scan")
+    got = q.find("*01*", 3901, STAMP)
+    assert list(got) == list(brute(idx, "*01*", 3901, STAMP))
+    assert q.last_plan["route"] == route
+    assert q.last_plan["name_prefilter"] == "host" and not q._names_cache
+    snap = tel.snapshot(traces=False)["metrics"]
+    assert [(s["labels"]["route"], s["value"]) for s in
+            snap["query_name_prefilter_total"]["series"]] == [("host", 1)]

@@ -1,5 +1,6 @@
 """Compile the four Pallas kernels for a described TPU v5e chip at the
-widths the main path runs them, and check the compile-cache helper.
+widths the main path runs them, the find's basename prefilter beside
+them, and check the compile-cache helper.
 
 Interpret mode accepts block shapes, casts and layouts the TPU compiler
 refuses; these compiles catch that without a chip. The topology is
@@ -132,6 +133,32 @@ def test_predeval_compiles_at_find_arena_shapes(one_chip, rows):
                               progs.setrows, progs.setcol, progs.setvals)),
              kernel=("predeval", "predeval"))
 
+
+
+@pytest.mark.parametrize("rows", [1 << 21, 1 << 22])
+def test_name_prefilter_compiles_at_find_arena_shapes(one_chip, rows):
+    """The find's basename prefilter, ANDed into one program's bitmap,
+    at the find cell's arena sizes with IO500's ``*01*`` literal: an XLA
+    fusion with no Pallas custom call, so the trace reduction counts
+    none of its ops as ``predeval`` or ``hashshard``."""
+    from jax._src.lib import xla_client
+
+    from repro.kernels.predeval import names as pk_names
+    lits, lens = pk_names.name_literals(["01"])
+    w = rows // 32
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = pk_names._and_row.lower(
+        s((1, w), jnp.uint32), s((), jnp.int32),
+        s((pk_names.NAME_BYTES, 32, w), jnp.uint8), s((32, w), jnp.bool_),
+        s(lits.shape, jnp.uint8), s(lens.shape, jnp.int32)).compile()
+    assert not _custom_calls(compiled)
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    assert not {trace_reduce.kernel_of(ln) for ln in text.splitlines()} \
+        & {"predeval", "hashshard"}
 
 def test_segstats_compiles_for_v5e(one_chip):
     cfg = PipelineConfig()
