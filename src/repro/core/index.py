@@ -5,8 +5,8 @@ ingest.
 The primary index is a columnar store over MetadataTable columns plus the
 host path array; the aggregate index holds DDSketch summaries per
 principal. Both expose the record schema the paper ingests into Globus
-Search (subject / visible_to / content) so the web-interface layer and the
-benchmarks read a uniform shape.
+Search (subject / visible_to / content) so the web-interface layer and
+every reader see a uniform shape.
 
 Consistency semantics (DESIGN.md §6): every mutation carries a version on
 one monotone logical clock shared by snapshot ingest and event ingest (a

@@ -253,14 +253,14 @@ class PolicyEngine:
                 "skipped": self.stats["skipped"],
             }
 
-    # -- the Robinhood-style baseline (for bench_rollup) ----------------------
+    # -- the Robinhood-style baseline ------------------------------------------
 
     def full_scan_baseline(self) -> Dict[str, bool]:
         """Judge every rule by brute force over ``primary.live()``,
         ignoring the rollup tree and all gating — the periodic
         full-namespace sweep this engine exists to replace. Returns
-        rule name -> violated; bench_rollup checks it agrees with the
-        incremental verdicts and times the two against each other."""
+        rule name -> violated; the tests check that it agrees with the
+        incremental verdicts."""
         if self.primary is None:
             raise RuntimeError("full_scan_baseline needs a primary index")
         live = self.primary.live()

@@ -828,7 +828,7 @@ class QueryEngine:
         return hier.hot_directories_scan(self.primary.live(),
                                          k=k, buckets=buckets)
 
-    # -- the full Table I suite, timed (for bench_index_query) ----------------
+    # -- the full Table I suite, each query timed on the host clock -----------
 
     def run_table1_suite(self) -> Dict[str, float]:
         timings = {}

@@ -37,9 +37,9 @@ fresh across a reconcile; compaction renumbers slots, so
 discovery state from the surviving live rows — a compacted shard keeps
 accelerating without any caller involvement.
 
-``benchmarks/bench_reconcile.py`` validates the two performance claims:
-scan-query throughput after compacting a heavily-tombstoned index, and
-reconcile cost vs a from-scratch rebuild at low drift.
+``tests/test_reconcile.py`` pins both as exactness contracts: a
+reconciled index equals a from-scratch rebuild, and compaction leaves
+every Table-I answer unchanged.
 """
 from __future__ import annotations
 

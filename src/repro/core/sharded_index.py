@@ -29,8 +29,8 @@ records across N ``PrimaryIndex`` shards by path hash:
   hash family. The global watermark/version clock is untouched: shards
   hold record versions, the ingestor holds the single watermark.
 
-``benchmarks/bench_sharded.py`` measures the resulting ingest/query
-throughput at 1/4/16 shards against the monolith.
+``tests/test_sharded_index.py`` pins the sharded layout's answers to
+the monolith's.
 """
 from __future__ import annotations
 

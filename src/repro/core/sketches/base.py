@@ -1,7 +1,7 @@
 """Uniform OO API over the four quantile sketches (Table VII).
 
 DDSketch is the production (device-native) sketch; the other three are
-mergeable host implementations used by the sketch-accuracy benchmark,
+mergeable host implementations used by the Table VII accuracy tests,
 mirroring the paper's evaluation of Datadog / Apache DataSketches
 implementations with default error parameters.
 """

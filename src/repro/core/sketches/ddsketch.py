@@ -21,7 +21,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,9 +208,3 @@ def summary(cfg: DDSketchConfig, state: Dict,
         "total": state["total"],
         "count": state["count"],
     }
-
-
-# -- numpy oracle (used by sketch-accuracy benchmarks & kernel tests) -------
-
-def np_quantile_exact(values: np.ndarray, q: float) -> float:
-    return float(np.quantile(values, q, method="lower"))

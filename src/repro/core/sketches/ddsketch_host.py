@@ -1,5 +1,5 @@
 """Host (numpy) DDSketch with the same bucket layout as the device sketch —
-used by the sketch-accuracy benchmark and as the oracle for the Pallas
+used by the Table VII accuracy tests and as the oracle for the Pallas
 kernel tests."""
 from __future__ import annotations
 

@@ -36,7 +36,7 @@ The compiler, the zone batch op and the host oracle are pure numpy;
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp

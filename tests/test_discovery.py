@@ -11,6 +11,8 @@ and 1/4 shards. The hypothesis leg sweeps that matrix; the crash leg
 pins that discovery state after checkpoint/restore + suffix replay
 matches the uninterrupted oracle's observable state.
 """
+import functools
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -166,10 +168,43 @@ def test_zone_map_prunes_runs():
 # planner equivalence: bulk, incremental, staleness, shards
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("layout", sorted(LAYOUTS))
-def test_bulk_equivalence(layout):
-    q, oracle, _ = make_pair(layout=layout, seed=2)
-    assert_equiv(q, oracle, expect_route="discovery", ctx=layout)
+#: selective variants of the Table-I queries: the regime the discovery
+#: index serves (interactive "find my files", policy candidate lists)
+SELECTIVE = {
+    "find_by_name": lambda q: q.find_by_name(r"/f123\d$"),
+    "find_by_glob": lambda q: q.find_by_glob("*/f39?9"),
+    "not_accessed_since": lambda q: q.not_accessed_since(365 * 86400),
+    "large_cold_files": lambda q: q.large_cold_files(1e7, 180 * 86400),
+    "past_retention": lambda q: q.past_retention(2 * 365 * 86400),
+    # all but the 4 rarest owners active: a deleted-user cleanup sweep
+    "owned_by_deleted_users":
+        lambda q: q.owned_by_deleted_users(list(range(28))),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def bulk_pair(layout):
+    """One read-only (accelerated, oracle) pair per layout, shared by
+    the bulk cases."""
+    return make_pair(layout=layout, seed=2)
+
+
+@pytest.mark.parametrize("layout,query", [
+    *(pytest.param(layout, None, id=layout) for layout in sorted(LAYOUTS)),
+    *(pytest.param(layout, query, id=f"{layout}-{query}")
+      for layout in sorted(LAYOUTS) for query in SELECTIVE)])
+def test_bulk_equivalence(layout, query):
+    """The Table-I suite (``query`` None), then each query at its
+    selective parameters, routes through discovery and byte-matches the
+    scan."""
+    q, oracle, _ = bulk_pair(layout)
+    if query is None:
+        assert_equiv(q, oracle, expect_route="discovery", ctx=layout)
+        return
+    a, b = SELECTIVE[query](q), SELECTIVE[query](oracle)
+    assert q.last_plan["route"] == "discovery", (layout, q.last_plan)
+    assert a.dtype == b.dtype, (layout, query)
+    assert np.array_equal(a, b), (layout, query, len(a), len(b))
 
 
 @pytest.mark.parametrize("layout", ["mono", "sharded4"])

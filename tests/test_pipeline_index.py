@@ -138,6 +138,35 @@ def test_query_engine_suite(fs):
             assert abs(got - exact) / exact < 1e-3
 
 
+def test_preprocess_reduces_row_volume(fs):
+    """Preprocessing keeps numeric columns and principal slots only: at
+    least 40% smaller than the paper's 22-column raw scan rows at 24
+    bytes per field (paper §V-A reports 40-90%)."""
+    rows_np, _ = snap.pad_rows(snap.preprocess(fs, PCFG), 256)
+    pre_bytes = sum(v.nbytes for v in rows_np.values())
+    assert pre_bytes <= 0.6 * len(fs) * 22 * 24
+
+
+def test_aggregate_totals_exact_for_every_principal(fs, rows):
+    """Every user's and group's file count in the aggregate index equals
+    the exact count, and its byte total the exact sum to f32 rounding."""
+    r, valid = rows
+    agg = AggregateIndex()
+    agg.from_sketch_state(
+        PCFG.sketch, snap.aggregate_local(PCFG, r, valid),
+        [f"user:{i}" for i in range(16)] + [f"group:{i}" for i in range(8)]
+        + [f"dir:{i}" for i in range(40)])
+    files = files_only(fs)
+    for kind, col, n in (("user", files.uid, 16), ("group", files.gid, 8)):
+        for p in range(n):
+            mask = (col % n) == p
+            rec = agg.get(f"{kind}:{p}")
+            assert rec["file_count"] == mask.sum(), (kind, p)
+            exact = float(files.size[mask].sum())
+            assert abs(rec["size"]["total"] - exact) <= 1e-5 * exact, \
+                (kind, p)
+
+
 def test_eventlog_roundtrip(tmp_path):
     log = EventLog()
     t = log.topic("audit", n_partitions=2)

@@ -167,7 +167,7 @@ def test_edge_delivery_is_per_transition():
 
 
 # ---------------------------------------------------------------------------
-# agreement with the full-scan baseline (bench_rollup's check, in-suite)
+# agreement with the full-scan baseline
 # ---------------------------------------------------------------------------
 
 def test_incremental_verdicts_match_full_scan_baseline():

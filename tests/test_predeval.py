@@ -4,6 +4,8 @@ Pins the three-way bit-identity (Pallas kernel / jitted jnp oracle /
 numpy host oracle) on the packed bitmaps, the candidate-superset
 property, and — through the engine — byte-identity with the numpy scan
 across layouts and batching."""
+import functools
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -229,27 +231,77 @@ def make_engines(layout, n_files=6000, seed=1):
             QueryEngine(b, AggregateIndex(), now=NOW, use_kernels=False))
 
 
-@pytest.mark.parametrize("layout", ["mono", "sharded4"])
-def test_engine_kernel_route_byte_identical(layout):
-    qk, qs = make_engines(layout)
-    for name, args, kw in MIX:
+#: the Table-I suite at its selective parameters (2-year cutoffs, an
+#: orphan sweep where all but the 4 rarest owners are active)
+SELECTIVE = {
+    "not_accessed_since": ((365 * 86400,), {}),
+    "large_cold_files": ((1e7, 180 * 86400), {}),
+    "past_retention": ((2 * 365 * 86400,), {}),
+    "owned_by_deleted_users": ((list(range(28)),), {}),
+}
+
+
+def dashboard_mix():
+    """32 panels: the four parametrised Table-I families swept over 7
+    thresholds each, plus 4 world-writable panels (DESIGN.md §13.4)."""
+    mix = []
+    for v in range(7):
+        months = (3 + 2 * v) * 30 * 86400
+        mix += [
+            ("not_accessed_since", (months,), {}),
+            ("large_cold_files", (10.0 ** (6 + v / 2), months), {}),
+            ("past_retention", (2 * months,), {}),
+            ("owned_by_deleted_users", (list(range(4 + 4 * v)),), {}),
+        ]
+    return mix + [("world_writable", (), {})] * 4
+
+
+@functools.lru_cache(maxsize=None)
+def shared_engines(layout, seed):
+    """Read-only (kernel, scan) engine pairs shared across cases."""
+    return make_engines(layout, seed=seed)
+
+
+@pytest.mark.parametrize("layout,query", [
+    *(pytest.param(layout, None, id=layout) for layout in LAYOUTS),
+    *(pytest.param(layout, query, id=f"{layout}-{query}")
+      for layout in LAYOUTS for query in SELECTIVE)])
+def test_engine_kernel_route_byte_identical(layout, query):
+    """The Table-I mix (``query`` None), then each query at its
+    selective parameters, takes the kernel route and byte-matches the
+    scan."""
+    qk, qs = shared_engines(layout, 1)
+    calls = MIX if query is None else [(query, *SELECTIVE[query])]
+    for name, args, kw in calls:
         a = getattr(qk, name)(*args, **kw)
         assert qk.last_plan["route"] == "kernel", (name, qk.last_plan)
         b = getattr(qs, name)(*args, **kw)
         assert qs.last_plan["route"] == "scan"
-        assert a.dtype == b.dtype and np.array_equal(a, b), name
+        assert a.dtype == b.dtype and np.array_equal(a, b), (name, args)
 
 
-@pytest.mark.parametrize("layout", ["mono", "sharded4"])
-def test_select_many_matches_individual(layout):
-    qk, qs = make_engines(layout, seed=2)
-    batch = qk.select_many(MIX + [("find_by_name", (r"/f1\d$",), {})])
-    assert qk.last_plan["query"] in ("select_many", "find_by_name")
-    for (name, args, kw), res in zip(MIX, batch):
+@pytest.mark.parametrize("layout,mix", [
+    *(pytest.param(layout, "table1", id=layout) for layout in LAYOUTS),
+    *(pytest.param(layout, "dashboard32", id=f"{layout}-dashboard32")
+      for layout in LAYOUTS)])
+def test_select_many_matches_individual(layout, mix):
+    qk, qs = shared_engines(layout, 2)
+    if mix == "table1":
+        specs = MIX
+        batch = qk.select_many(MIX + [("find_by_name", (r"/f1\d$",), {})])
+        assert qk.last_plan["query"] in ("select_many", "find_by_name")
+        # the non-predicate tail entry dispatched normally
+        assert np.array_equal(batch[-1], qs.find_by_name(r"/f1\d$"))
+    else:
+        specs = dashboard_mix()
+        batch = qk.select_many(specs)
+        # every panel joined the one stacked pass
+        assert (qk.last_plan["batched"], qk.last_plan["fallback"]) == \
+            (len(specs), 0)
+    for (name, args, kw), res in zip(specs, batch):
         ref = getattr(qs, name)(*args, **kw)
-        assert res.dtype == ref.dtype and np.array_equal(res, ref), name
-    # the non-predicate tail entry dispatched normally
-    assert np.array_equal(batch[-1], qs.find_by_name(r"/f1\d$"))
+        assert res.dtype == ref.dtype and np.array_equal(res, ref), \
+            (name, args)
 
 
 def test_select_many_pins_one_clock():

@@ -36,9 +36,10 @@ from repro.core import events as ev
 from repro.core.discovery import rebuild_discovery
 from repro.core.event_ingest import EventIngestor, IngestConfig
 from repro.core.index import AggregateIndex, PrimaryIndex
+from repro.core.metadata import files_only, synth_filesystem
 from repro.core.query import QueryEngine
 from repro.core.query_service import QueryService, ResultCache
-from repro.core.sharded_index import index_from_state
+from repro.core.sharded_index import ShardedPrimaryIndex, index_from_state
 
 NOW = 2e6          # mtimes are uniform(1, 1e6): the cutoffs below split
 
@@ -632,3 +633,98 @@ def test_query_batch_time_relative_uses_one_clock():
                          ("past_retention", 500.0)])
     assert [list(x["result"]) for x in r] == [["/fs/a"], ["/fs/a"]]
     assert not any(x["freshness"]["cached"] for x in r)
+
+
+# ---------------------------------------------------------------------------
+# readers through the service while out-of-band churn lands
+# ---------------------------------------------------------------------------
+
+N_CHURN = 5
+
+
+@pytest.fixture(scope="module")
+def churned_service():
+    """Four reader threads run a dashboard mix (16 cache keys) through a
+    ``QueryService`` over a 4-shard index with discovery attached, while
+    a writer applies ``N_CHURN`` versioned ``upsert_batch`` rewrites
+    directly (the out-of-band path, caught by the epoch probe). Readers
+    keep going until the writer is done, then make two more passes.
+    Returns the observations the tests below read."""
+    files = files_only(synth_filesystem(4000, seed=9))
+    idx = ShardedPrimaryIndex(4)
+    idx.ingest_table(files, 1)
+    idx.attach_discovery()
+    svc = QueryService(idx, AggregateIndex(), now=1.7e9, max_readers=4)
+    probes = list(files.paths[:4])
+    mix = []
+    for v in range(4):
+        mix += [("find_by_glob", f"*/f{31 + v}??"),
+                ("stat", probes[v]),
+                ("not_accessed_since", (180 + 60 * v) * 86400),
+                ("owned_by_deleted_users", list(range(20 + 2 * v)))]
+    version_before = svc.freshness()["served_watermark"]
+    rng = np.random.default_rng(7)
+    churn = []
+    for i in range(N_CHURN):
+        pick = rng.choice(len(files), size=400, replace=False)
+        churn.append((list(files.paths[pick]),
+                      {"path_hash": files.path_hash[pick],
+                       "size": files.size[pick].astype(np.float32) + i,
+                       "atime": files.atime[pick].astype(np.float32)},
+                      np.full(len(pick), 2 + i, np.int64)))
+    done = threading.Event()
+    errors = []
+
+    def reader(rid):
+        try:
+            extra = 2
+            while extra:
+                if done.is_set():
+                    extra -= 1
+                for m in range(len(mix)):
+                    name, *args = mix[(m + rid) % len(mix)]
+                    svc.query(name, *args)
+        except BaseException as e:             # pragma: no cover
+            errors.append(repr(e))
+
+    def writer():
+        for paths, fields, vers in churn:
+            idx.upsert_batch(paths, fields, vers)
+            time.sleep(0.01)
+        done.set()
+
+    threads = [threading.Thread(target=reader, args=(i,)) for i in range(4)]
+    threads.append(threading.Thread(target=writer))
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=600)
+    assert not errors, errors
+    fr = svc.freshness()
+    svc.close()
+    return {"freshness": fr, "version_before": version_before,
+            "pins_after_close": idx.snapshot_stats()}
+
+
+def test_churn_leaks_no_snapshot(churned_service):
+    assert churned_service["freshness"]["open_snapshots"] == 0
+    assert churned_service["pins_after_close"] == {"open_snapshots": 0,
+                                                   "pinned_epochs": 0}
+
+
+def test_churn_hit_rate_strictly_between_zero_and_one(churned_service):
+    """All hits would mean the churn never invalidated; all misses, that
+    the cache never served."""
+    cache = churned_service["freshness"]["cache"]
+    assert 0.0 < cache["hit_rate"] < 1.0, cache
+    assert cache["invalidations"] >= 1, cache
+
+
+def test_churn_advances_data_version(churned_service):
+    """The out-of-band writes advance the served data version, one step
+    per change the epoch probe caught (batches landing between two
+    snapshots count once), each step with its cache invalidation."""
+    fr = churned_service["freshness"]
+    steps = fr["served_watermark"] - churned_service["version_before"]
+    assert 1 <= steps <= N_CHURN
+    assert steps == fr["cache"]["invalidations"]

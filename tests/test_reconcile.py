@@ -372,6 +372,36 @@ def test_compact_preserves_state_both_slot_maps(slot_map_factory):
     assert len(idx) == len(files)
 
 
+def table1_answers(q):
+    """The Table-I suite's answers, order-free (compaction renumbers
+    slots, and scan order follows slots)."""
+    return [sorted(q.find_by_name(r"f1\d\d$")),
+            sorted(q.not_accessed_since(90 * 86400)),
+            sorted(q.large_cold_files(1e5, 180 * 86400)),
+            sorted(q.world_writable()),
+            sorted(q.past_retention(2 * 365 * 86400)),
+            {k: sorted(v) for k, v in q.duplicate_candidates().items()}]
+
+
+@pytest.mark.parametrize("n_shards", [None, 4])
+def test_compaction_leaves_table1_answers_unchanged(n_shards):
+    """An engine that has already served the Table-I suite (its arenas
+    cached) answers every query the same after compacting a 70%
+    tombstoned index."""
+    files = files_only(synth_filesystem(3000, n_dirs=100, seed=4))
+    idx = make_primary(n_shards)
+    idx.ingest_table(files, 1)
+    rng = np.random.default_rng(4)
+    doomed = rng.choice(files.paths, size=int(0.7 * len(files)),
+                        replace=False)
+    idx.delete_batch(list(doomed), np.full(len(doomed), 2, np.int64))
+    q = QueryEngine(idx, AggregateIndex(), now=1.7e9)
+    before = table1_answers(q)
+    assert all(len(ans) for ans in before[:5])   # the scans match rows
+    assert compact_if_needed(idx, threshold=0.3) == len(doomed)
+    assert table1_answers(q) == before
+
+
 def test_sharded_compact_per_shard_threshold():
     """Each shard compacts independently: deleting one shard's records
     rewrites only that shard (others keep their slot count)."""

@@ -12,6 +12,7 @@ replica lag exports through ``ReplicatedQueryService.freshness`` /
 ``merge_freshness`` / ``Monitor``.
 """
 import os
+import threading
 import zlib
 
 import numpy as np
@@ -400,3 +401,90 @@ def test_remove_follower_releases_retention(tmp_path):
     bases = [p.base for t in group.log.topics.values()
              for p in t.partitions]
     assert sum(bases) > 0                      # retention proceeds
+
+
+# ---------------------------------------------------------------------------
+# reads, churn and follower sync at once
+# ---------------------------------------------------------------------------
+
+def _bootstrapped_group(tmp_path, n_followers, seed=29):
+    """A 2-shard group whose leader applied and shipped the first half
+    of a workload before ``n_followers`` followers bootstrapped from the
+    shipped checkpoint. Returns (group, the unproduced second half)."""
+    batches, names = _workload(seed=seed, n_ops=300)
+    group = _group("eager", 2, tmp_path / "ship")
+    half = len(batches) // 2
+    for i, b in enumerate(batches[:half]):
+        group.produce(b, names=names if i == 0 else None)
+    group.leader.pipeline.drain()
+    group.checkpoint()
+    for _ in range(n_followers):
+        group.add_follower()
+    return group, batches[half:]
+
+
+def test_tokenless_reads_under_churn_never_reach_leader(tmp_path):
+    """Reader threads send token-less queries while the leader takes
+    churn (produce, pump, ship every 4 batches) and the followers sync:
+    every read is served by a follower, and once the log is drained the
+    followers byte-match the leader."""
+    group, rest = _bootstrapped_group(tmp_path, 2)
+    svc = ReplicatedQueryService(group)
+    mix = [("find_by_glob", f"/fs/*{i}*") for i in range(8)] + \
+        [("world_writable",), ("per_user_usage",)]
+    done = threading.Event()
+    errors = []
+
+    def reader():
+        try:
+            extra = 1
+            while extra:
+                if done.is_set():
+                    extra -= 1
+                for name, *args in mix:
+                    svc.query(name, *args)
+        except BaseException as e:             # pragma: no cover
+            errors.append(repr(e))
+
+    def churner():
+        for k, b in enumerate(rest):
+            group.produce(b)
+            group.pump()
+            if (k + 1) % 4 == 0:
+                group.checkpoint()
+        done.set()
+
+    def syncer():
+        while not done.wait(0.01):
+            group.sync_followers()
+
+    threads = [threading.Thread(target=f)
+               for f in (churner, syncer, reader, reader, reader)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=600)
+    assert not errors, errors
+    assert svc.stats["leader_reads"] == 0
+    assert svc.stats["follower_reads"] == svc.stats["queries"] > 0
+    group.leader.pipeline.drain()
+    group.sync_followers(drain=True)
+    for rep in group.followers.values():
+        _assert_replica_equals(rep, group.leader, f"follower {rep.rid}")
+
+
+def test_followers_byte_match_promoted_leader_after_failover(tmp_path):
+    """Kill the leader with an unsynced tail in the log: the promoted
+    follower applies everything produced, and the surviving followers,
+    synced to the new leader's shipped checkpoint, byte-match it."""
+    group, rest = _bootstrapped_group(tmp_path, 3)
+    group.sync_followers()
+    for b in rest:                             # the tail no follower saw
+        group.produce(b)
+    promoted = group.failover(drain=True)
+    assert promoted.applied_seq() == group.token
+    group.checkpoint()
+    group.sync_followers(drain=True)
+    assert len(group.followers) == 2
+    for rep in group.followers.values():
+        _assert_replica_equals(rep, promoted, f"follower {rep.rid}")

@@ -19,6 +19,8 @@ from repro.core import hierarchy as hier
 from repro.core.event_ingest import EventIngestor, IngestConfig
 from repro.core.hierarchy import resolve_paths_host
 from repro.core.index import AggregateIndex
+from repro.core.metadata import TYPE_DIR, synth_filesystem
+from repro.core.policy import PolicyEngine, Rule
 from repro.core.query import QueryEngine
 from repro.core.query_service import QueryService
 from repro.core.reconcile import compact_if_needed, reconcile
@@ -243,6 +245,88 @@ def test_propagation_is_incremental():
     assert 0 < work <= depth_bound, (work, depth_bound)
     assert work < n_nodes / 2            # nowhere near a full recompute
     assert_rollup_equals_scan(h, primary, "incremental touch")
+
+
+# ---------------------------------------------------------------------------
+# a deep snapshot tree handed to the event path
+# ---------------------------------------------------------------------------
+
+N_CHURN, CHURN_FILES = 4, 50
+
+
+def build_deep(n_files=6000, n_dirs=600):
+    """Snapshot-ingest a tree at least 8 directories deep, then hand off
+    to the event path: ``register_tree`` reseeds the rollups and
+    registers every directory plus the files churn will touch (fid =
+    table row). Returns (table, primary, ingestor, churn victims)."""
+    table = synth_filesystem(n_files, n_dirs=n_dirs, max_depth=12, seed=8)
+    assert int(table.depth.max()) >= 8
+    primary = make_primary(None)
+    primary.ingest_table(table, version=0)
+    ing = EventIngestor(
+        IngestConfig(mode="eager", pad_to=64, max_buffer_events=256,
+                     freshness_window=1e9, update_aggregates=False),
+        PCFG, primary, AggregateIndex())
+    dirs = np.flatnonzero(table.type == TYPE_DIR)
+    victims = np.random.default_rng(17).choice(
+        np.flatnonzero(table.type != TYPE_DIR), size=N_CHURN * CHURN_FILES,
+        replace=False)
+    rows = np.concatenate([dirs, victims])
+    ing.register_tree(
+        parents={int(r): int(table.parent[r]) for r in rows},
+        names={int(r): str(table.paths[r]).rsplit("/", 1)[-1] if r else "fs"
+               for r in rows},
+        is_dir={int(r): True for r in dirs})
+    assert ing.hierarchy.exact
+    return table, primary, ing, victims
+
+
+def test_deep_tree_du_matches_scan_at_every_depth():
+    """``du`` through the rollups equals the scan over ``live()`` at
+    every depth of the tree, from the root and from two mid-depth
+    directories with large subtrees."""
+    table, primary, ing, _ = build_deep()
+    h = ing.hierarchy
+    q = QueryEngine(primary, AggregateIndex(), now=1.7e9, ingestor=ing)
+    mids = [r["path"] for r in h.hot_directories(
+        k=64, buckets=hier.N_ATIME_BUCKETS) if 2 <= r["path"].count("/") <= 4]
+    assert len(mids) >= 2
+    live = primary.live()
+    for path in ["/fs"] + mids[:2]:
+        for d in range(int(table.depth.max()) + 1):
+            assert q.du(path, depth=d) == hier.du_scan(live, path, depth=d), \
+                (path, d)
+            assert q.last_plan["route"] == "rollup", (path, q.last_plan)
+
+
+def test_deep_tree_policy_sweeps_under_churn_match_full_scan():
+    """Incremental policy sweeps, one per churn batch of stat updates,
+    reach the same verdicts as the full-namespace scan baseline, and
+    each batch's propagation touches well under half of the tree."""
+    _, primary, ing, victims = build_deep()
+    h = ing.hierarchy
+    proj = [r["path"] for r in h.hot_directories(k=8)]
+    rules = [Rule(f"proj{i}", "max_bytes", path=p, limit_bytes=1 << 44)
+             for i, p in enumerate(proj)]
+    rules += [Rule("ret2y", "retention", path="/fs", max_age_s=730 * 86400),
+              Rule("u1", "uid_quota", uid=1, limit_bytes=1 << 62),
+              Rule("u2_tight", "uid_quota", uid=2, limit_bytes=1)]
+    eng = PolicyEngine(rules, hierarchy=h, primary=primary)
+    eng.evaluate(watermark=0)
+    stream = ev.EventStream(start_fid=len(primary) + 1_000_000)
+    for i in range(N_CHURN):
+        for r in victims[i * CHURN_FILES:(i + 1) * CHURN_FILES]:
+            stream.emit(ev.E_SATTR, int(r), has_stat=1,
+                        size=float(1024 + r % 4096), mtime=1.7e9 - 3600.0)
+        before = h.stats["propagated"]
+        ing.ingest(stream.take(None))
+        ing.flush()
+        eng.evaluate(watermark=int(ing.freshness()["applied_seq"]))
+        assert h.stats["propagated"] - before < h._n / 2, i
+    verdicts = {r.name: r.name in eng.violations() for r in rules}
+    assert verdicts == eng.full_scan_baseline()
+    assert verdicts["u2_tight"]                # a violation really fired
+    assert_rollup_equals_scan(h, primary, "deep churn")
 
 
 def test_rollup_state_roundtrip_is_byte_identical():

@@ -11,6 +11,8 @@
   produce / pump / flush / crash never skips an offset, never commits
   one backwards, and full redelivery from offset zero is idempotent on
   the index (the exactly-once-effect claim, DESIGN.md §10.2).
+- The log leg: a changelog through produce -> log -> consumer group
+  leaves the index byte-identical to feeding the ingestor directly.
 """
 import os
 
@@ -27,6 +29,7 @@ from repro.core.index import AggregateIndex, PrimaryIndex
 from repro.core.metadata import synth_filesystem
 from repro.core.sharded_index import ShardedPrimaryIndex, index_from_state
 from repro.core.stream_pipeline import DurablePipeline
+from test_differential import assert_byte_identical
 
 PCFG = snap.PipelineConfig(n_users=8, n_groups=4, n_dirs=16)
 
@@ -417,3 +420,67 @@ def test_restore_resets_producer_routing_to_checkpoint_bindings():
     grew = [i for i, p in enumerate(pipe.topic.partitions)
             if p.end > ends_before[i]]
     assert grew == [int(path_hashes([f"#{fid}"])[0]) % 4]
+
+
+# ---------------------------------------------------------------------------
+# the log leg: the same changelog through the log or fed directly
+# ---------------------------------------------------------------------------
+
+def _tree_and_creates(n_files, n_dirs=64, batch=512, seed=1):
+    """A random directory tree (one mkdir batch), then stat-carrying
+    creates under it in ``batch``-sized changelog batches."""
+    rng = np.random.default_rng(seed)
+    dfids = np.arange(1, n_dirs + 1)
+    b = ev.empty_batch(n_dirs)
+    b["seq"] = dfids.astype(np.int64)
+    b["etype"][:] = ev.E_MKDIR
+    b["fid"] = dfids.astype(np.int32)
+    b["parent_fid"][1:] = rng.integers(0, dfids[:-1] + 1).astype(np.int32)
+    b["is_dir"][:] = 1
+    batches = [b]
+    names = {0: "fs", **{int(d): f"d{d}" for d in dfids}}
+    ffids = np.arange(n_dirs + 1, n_dirs + 1 + n_files)
+    names.update({int(f): f"f{f}" for f in ffids})
+    for lo in range(0, n_files, batch):
+        f = ffids[lo:lo + batch]
+        bb = ev.empty_batch(len(f))
+        bb["seq"] = f.astype(np.int64)
+        bb["etype"][:] = ev.E_CREAT
+        bb["fid"] = f.astype(np.int32)
+        bb["parent_fid"] = rng.integers(1, n_dirs + 1, len(f)).astype(np.int32)
+        bb["has_stat"][:] = 1
+        bb["size"] = rng.gamma(1.5, 1e4, len(f)).astype(np.float32)
+        bb["mtime"] = rng.uniform(1, 1e6, len(f)).astype(np.float32)
+        bb["uid"] = rng.integers(0, PCFG.n_users, len(f)).astype(np.int32)
+        bb["gid"] = (bb["uid"] % PCFG.n_groups).astype(np.int32)
+        batches.append(bb)
+    return batches, names
+
+
+def test_log_leg_loses_no_record():
+    """Produce -> partitioned log -> consumer group -> commit-after-apply
+    leaves a 4-shard index byte-identical to feeding the ingestor the
+    same batches directly, with the same applied watermark and no lag."""
+    batches, names = _tree_and_creates(6000)
+
+    def ingestor():
+        primary = ShardedPrimaryIndex(4)
+        return primary, EventIngestor(
+            IngestConfig(mode="eager", pad_to=512, update_aggregates=False),
+            PCFG, primary, AggregateIndex())
+
+    direct, ing_direct = ingestor()
+    for k, b in enumerate(batches):
+        ing_direct.ingest(b, names=names if k == 0 else None)
+    logged, ing_log = ingestor()
+    pipe = DurablePipeline(EventLog(), ing_log, n_partitions=4,
+                           batch_size=512)
+    for k, b in enumerate(batches):
+        pipe.produce(b, names=names if k == 0 else None)
+    pipe.drain()
+    assert len(logged) == len(direct) == 6000
+    assert_byte_identical(logged.live(), direct.live(), "log leg")
+    assert ing_log.freshness()["applied_seq"] == \
+        ing_direct.freshness()["applied_seq"]
+    assert pipe.lag() == 0
+    assert ing_log.metrics["unresolved"] == 0     # every path resolved
