@@ -283,12 +283,22 @@ class ShardedPrimaryIndex:
                           for s in range(n_shards)]
         self._c_delete = [fam.labels(str(s), "delete")
                           for s in range(n_shards)]
+        self._route_rows = self.telemetry.counter(
+            "index_route_rows_total",
+            "rows routed on the device by the width bucket of their batch",
+            labels=("width",))
+        self._bind_route_rows()
         # top-level MVCC write lock (DESIGN.md §12): cross-shard
         # mutations and snapshot pinning serialize here, then take the
         # per-shard locks inside — one consistent order, no deadlock
         self._lock = threading.RLock()
 
     # -- routing --------------------------------------------------------------
+
+    def _bind_route_rows(self) -> None:
+        from repro.kernels.hashshard.ref import width_buckets
+        self._c_route_rows = {w: self._route_rows.labels(str(w))
+                              for w in width_buckets(self.route_width)}
 
     def shard_of(self, path: str) -> int:
         return shard_of(path, self.n_shards)
@@ -310,16 +320,19 @@ class ShardedPrimaryIndex:
 
     def _route_device(self, paths: Sequence[str]) -> np.ndarray:
         """Batch routing through the hashshard op (paper's crc32-shard
-        analogue, §IV-A2). Rows longer than the packing width cannot be
-        width-truncated without desyncing from the host fallback — they
-        are patched via ``md.path_hash``."""
+        analogue, §IV-A2). Rows are cut at the batch's own width bucket
+        (``encode_strings_joined``: the longest path's power of two, at
+        least 32, at most ``route_width``). Rows longer than
+        ``route_width`` cannot be width-truncated without desyncing from
+        the host fallback — they are patched via ``md.path_hash``."""
         from repro.core.index import bucket_pow2
         from repro.kernels.hashshard import ops as hs_ops
-        from repro.kernels.hashshard.ref import encode_strings_np
+        from repro.kernels.hashshard.ref import encode_strings_joined
         n = len(paths)
         with self._span_encode:
-            rows, lens, truncated = encode_strings_np(paths,
-                                                      self.route_width)
+            rows, lens, truncated = encode_strings_joined(paths,
+                                                          self.route_width)
+        self._c_route_rows[rows.shape[1]].inc(n)
         with self._span_device:           # H2D, kernel, readback
             pad = bucket_pow2(n) - n      # O(log N) jit shape universe
             if pad:
@@ -567,6 +580,7 @@ class ShardedPrimaryIndex:
             slot_map_factory = self.slot_map_factory
         self.kernel_route_min = state["kernel_route_min"]
         self.route_width = state["route_width"]
+        self._bind_route_rows()
         for sh, sub in zip(self.shards, state["shards"]):
             sh.load_state(sub, slot_map_factory)
 

@@ -52,7 +52,7 @@ def encode_strings(strings, width: int = 128):
 
 def encode_strings_np(strings, width: int = 128):
     """Vectorized ``encode_strings`` (numpy bytes coercion instead of a
-    per-row Python loop) for the batch-routing hot path. Returns
+    per-row Python loop), zero-padded to the full ``width``. Returns
     (rows, lens, truncated): ``truncated`` marks rows longer than
     ``width`` whose hash would desync from the full-length host hash —
     callers patch those through the scalar fallback. Non-ASCII batches
@@ -83,3 +83,56 @@ def encode_strings_np(strings, width: int = 128):
     elif w > width:
         mat = np.ascontiguousarray(mat[:, :width])
     return mat, np.minimum(full_lens, width), full_lens > width
+
+
+#: narrowest row width of a joined-buffer encode
+MIN_ROUTE_WIDTH = 32
+
+
+def width_bucket(max_len: int, cap: int) -> int:
+    """Row width for a batch whose longest path is ``max_len`` bytes: the
+    power of two at or above it, at least ``MIN_ROUTE_WIDTH``, capped at
+    ``cap``."""
+    return min(cap, max(MIN_ROUTE_WIDTH,
+                        1 << max(int(max_len) - 1, 0).bit_length()))
+
+
+def width_buckets(cap: int):
+    """Every width ``width_bucket`` can return under ``cap``."""
+    out, w = [], MIN_ROUTE_WIDTH
+    while w < cap:
+        out.append(w)
+        w *= 2
+    return out + [cap]
+
+
+def encode_strings_joined(strings, width: int = 128):
+    """``encode_strings_np`` for the batch-routing hot path, at the
+    batch's own width: the batch is joined into one UTF-8 buffer
+    (``surrogatepass``, as the scalar hash family encodes), the NUL
+    separators give each row's start and byte length, and one gather
+    over a strided view of the buffer cuts the rows out at
+    ``width_bucket(longest, width)`` bytes. Returns (rows, lens,
+    truncated) as ``encode_strings_np`` does, except that bytes past a
+    row's length are not zeroed (they hold the separator and the next
+    paths): the hashshard kernel and its jnp oracle read only
+    ``i < len``. A batch in which some path holds a NUL cannot be split
+    on the separators and falls back to ``encode_strings_np``."""
+    n = len(strings)
+    raw = ("\0".join(strings) + "\0" * width).encode("utf-8",
+                                                       "surrogatepass")
+    buf = np.frombuffer(raw, np.uint8)
+    m = len(buf) - width
+    seps = np.flatnonzero(buf[:m] == 0)
+    if len(seps) != n - 1:
+        return encode_strings_np(strings, width)
+    starts = np.empty(n, np.int64)
+    starts[0] = 0
+    starts[1:] = seps + 1
+    full = np.empty(n, np.int64)
+    full[:-1] = seps
+    full[-1] = m
+    full -= starts
+    wb = width_bucket(full.max(), width)
+    rows = np.lib.stride_tricks.sliding_window_view(buf, wb)[starts]
+    return rows, np.minimum(full, width).astype(np.int32), full > width

@@ -9,8 +9,9 @@ from repro.core.sketches.ddsketch import DDSketchConfig
 from repro.kernels.ddsketch.ddsketch import grouped_update_pallas
 from repro.kernels.ddsketch.ref import grouped_update_ref
 from repro.kernels.hashshard.hashshard import hashshard_pallas
-from repro.kernels.hashshard.ref import (encode_strings, hashshard_host,
-                                         hashshard_ref)
+from repro.kernels.hashshard.ref import (encode_strings,
+                                         encode_strings_joined,
+                                         hashshard_host, hashshard_ref)
 from repro.kernels.segstats.segstats import segstats_pallas
 from repro.kernels.segstats.ref import segstats_ref
 
@@ -187,6 +188,22 @@ def test_hashshard_kernel_matches_host():
     h_ref, s_ref = hashshard_ref(jnp.asarray(rows), jnp.asarray(lens))
     h_host, s_host = hashshard_host(strings)
     np.testing.assert_array_equal(np.asarray(h_dev), np.asarray(h_ref))
+    np.testing.assert_array_equal(np.asarray(h_dev), h_host)
+    np.testing.assert_array_equal(np.asarray(s_dev), s_host)
+
+
+def test_hashshard_reads_only_bytes_within_length():
+    """Joined-buffer rows hold the separator and the next paths past
+    each row's length: the kernel and its jnp oracle still give the host
+    hash, which reads exactly ``len`` bytes."""
+    strings = [f"/fs/dé{i % 5}/f{i}" for i in range(300)] + [""]
+    rows, lens, trunc = encode_strings_joined(strings, width=64)
+    assert rows.shape[1] == 32 and not trunc.any()
+    assert rows[0, lens[0]] == 0 and rows[0, lens[0] + 1] == ord("/")
+    h_dev, s_dev = hashshard_pallas(jnp.asarray(rows), jnp.asarray(lens))
+    h_ref, _ = hashshard_ref(jnp.asarray(rows), jnp.asarray(lens))
+    h_host, s_host = hashshard_host(strings)
+    np.testing.assert_array_equal(np.asarray(h_ref), h_host)
     np.testing.assert_array_equal(np.asarray(h_dev), h_host)
     np.testing.assert_array_equal(np.asarray(s_dev), s_host)
 

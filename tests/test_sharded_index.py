@@ -129,6 +129,89 @@ def test_encode_strings_np_matches_loop_encoder():
     assert trunc.tolist() == [False, False, True, False]
 
 
+def _route_cases():
+    import os
+    return {
+        "ascii": [f"/fs/d{i % 7}/f{i}" for i in range(40)],
+        "utf8": [f"/fs/dé{i}/ファイル{i}.dat" for i in range(40)],
+        "surrogate": [os.fsdecode(b"/fs/raw\xff\xfe%d" % i)
+                      for i in range(40)],
+        "empty": ["", "/fs/a", "", "/fs/b/c", ""],
+        "nul": ["/fs/a\0b", "/fs/c", "/fs/d/e"],
+        "over_width": ["/fs/a", "/fs/" + "z" * 300, "/fs/é" * 70],
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_route_cases()))
+def test_joined_encode_route_matches_path_hash(case):
+    """The device route, fed by the joined-buffer encoder, equals the
+    scalar hash for ASCII, multi-byte UTF-8, lone surrogates from
+    ``os.fsdecode``, empty strings, a path holding a NUL (the fallback
+    encoder) and paths longer than ``route_width`` (patched)."""
+    from repro.kernels.hashshard.ref import encode_strings_joined
+    paths = _route_cases()[case]
+    idx = ShardedPrimaryIndex(5, kernel_route_min=1)
+    rows, lens, trunc = encode_strings_joined(paths, idx.route_width)
+    for i, p in enumerate(paths):
+        raw = p.encode("utf-8", "surrogatepass")
+        assert lens[i] == min(len(raw), idx.route_width)
+        assert bytes(rows[i, :lens[i]]) == raw[:lens[i]]
+        assert trunc[i] == (len(raw) > idx.route_width)
+    assert [int(h) for h in idx._route_device(paths)] == [
+        path_hash(p) for p in paths]
+
+
+@pytest.mark.parametrize("longest,width", [
+    (1, 32), (31, 32), (32, 32), (33, 64), (64, 64), (65, 128),
+    (128, 128), (129, 192), (192, 192), (193, 192)])
+def test_joined_encode_width_bucket(longest, width):
+    """Rows are cut at the power of two at or above the batch's longest
+    path, at least 32 and at most ``route_width`` (192); hashes equal
+    the scalar hash on both sides of every bucket edge."""
+    from repro.kernels.hashshard.ref import encode_strings_joined
+    paths = [f"/f{i}"[:longest] for i in range(63)] + ["/" * longest]
+    rows, lens, trunc = encode_strings_joined(paths, 192)
+    assert rows.shape == (64, width)
+    assert int(lens.max()) == min(longest, 192)
+    assert trunc.tolist() == [False] * 63 + [longest > 192]
+    idx = ShardedPrimaryIndex(3, kernel_route_min=1)
+    assert [int(h) for h in idx._route_device(paths)] == [
+        path_hash(p) for p in paths]
+
+
+def test_joined_encode_list_and_ndarray_agree():
+    from repro.kernels.hashshard.ref import encode_strings_joined
+    paths = [f"/fs/d{i % 3}/é{i}" for i in range(50)] + [""]
+    got_l = encode_strings_joined(paths, 64)
+    got_a = encode_strings_joined(np.asarray(paths, object), 64)
+    for a, b in zip(got_l, got_a):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_route_rows_counter_counts_device_rows_by_width():
+    """``index_route_rows_total{width}`` counts each device-routed row
+    once, under its batch's width bucket; host-routed batches and
+    batches that carry hashes leave it untouched."""
+    from repro.core.telemetry import Telemetry
+    tel = Telemetry()
+    idx = ShardedPrimaryIndex(4, kernel_route_min=16, telemetry=tel)
+
+    def counts():
+        fam = tel.snapshot(traces=False)["metrics"][
+            "index_route_rows_total"]
+        return {s["labels"]["width"]: s["value"] for s in fam["series"]
+                if s["value"]}
+
+    short = [f"/fs/f{i}" for i in range(20)]
+    idx.route(short[:10])
+    idx.route(short, hashes=path_hashes(short))
+    assert counts() == {}
+    idx.route(short)
+    idx.route(short[:17] + ["/fs/" + "x" * 60])
+    idx.route(short[:16] + ["/fs/" + "y" * 200])
+    assert counts() == {"32": 20, "64": 18, "192": 17}
+
+
 # ---------------------------------------------------------------------------
 # HashSlotMap == DictSlotMap (behavioral parity)
 # ---------------------------------------------------------------------------

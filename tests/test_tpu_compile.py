@@ -241,6 +241,20 @@ def test_hashshard_compiles_for_v5e(one_chip):
     _compile(fn, rows, lens, kernel=("hashshard", "hashshard"))
 
 
+@pytest.mark.parametrize("width", [32, 64, 128])
+def test_hashshard_compiles_at_batch_width_buckets(one_chip, width):
+    """The narrower widths a routed batch takes (its longest path's
+    width bucket) at the re-scan's 262,144-row chunk; the trace
+    reduction still knows the kernel by its byte planes."""
+    n = 1 << 18
+    rows = jax.ShapeDtypeStruct((n, width), jnp.uint8, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+    def fn(b, m):
+        return hashshard_pallas(b, m, 4, interpret=False)
+    _compile(fn, rows, lens, kernel=("hashshard", "hashshard"))
+
+
 @pytest.fixture
 def cache_dir_config():
     old = jax.config.jax_compilation_cache_dir
